@@ -40,6 +40,8 @@ for _p in (_ROOT, os.path.join(_ROOT, "src")):
 
 import numpy as np
 
+from benchmarks.common import cpu_worker_env
+
 
 def _dataset(C, L, M, T, n_queries, seed):
     rng = np.random.default_rng(seed)
@@ -177,6 +179,7 @@ def _chaos_worker(n_requests: int) -> Dict:
 
 def run(quick: bool = False, out: str = "BENCH_chaos.json") -> Dict:
     n = 128 if quick else 512
+    env = cpu_worker_env(4)       # refuses up front off the CPU setup
     print("## supervision overhead (no faults)")
     overhead = supervision_overhead(n_requests=min(n, 256))
     print(f"bare {overhead['bare']['qps']:.1f} q/s | supervised "
@@ -186,12 +189,6 @@ def run(quick: bool = False, out: str = "BENCH_chaos.json") -> Dict:
     print("## chaos recovery (4-shard mesh, kill + shard outage)")
     cmd = [sys.executable, "-m", "benchmarks.chaos_serving",
            "--worker", str(n)]
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=4",
-               JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"),
-               PYTHONPATH=os.pathsep.join(
-                   [os.path.join(_ROOT, "src"), _ROOT,
-                    os.environ.get("PYTHONPATH", "")]).rstrip(os.pathsep))
     proc = subprocess.run(cmd, capture_output=True, text=True, timeout=900,
                           cwd=_ROOT, env=env)
     if proc.returncode != 0:

@@ -10,6 +10,7 @@ coverage budgets.
 from __future__ import annotations
 
 import functools
+import os
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -18,8 +19,26 @@ from repro.configs.base import BanditConfig
 from repro.data.synthetic import RetrievalDataset, make_retrieval_dataset
 from repro.retrieval.pipeline import evaluate_dataset
 
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DEFAULT_ALPHAS = (0.05, 0.15, 0.3, 0.6, 1.0, 2.0)
 DEFAULT_BUDGETS = (0.1, 0.2, 0.3, 0.4, 0.5, 0.65, 0.8, 1.0)
+
+
+def cpu_worker_env(n_devices: int) -> Dict[str, str]:
+    """Environment for a worker process on ``n_devices`` virtual CPU
+    devices. Only under the CPU setup (``JAX_PLATFORMS=cpu``): on a chip
+    machine the launching process has already used JAX and holds the chip,
+    and a CPU worker's timings would pass for the chip's. There, run the
+    worker's ``--worker`` entry point directly, in a process of its own."""
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        raise RuntimeError(
+            "this phase starts a worker on virtual CPU devices and runs only "
+            "under JAX_PLATFORMS=cpu; on a chip machine run its --worker "
+            "entry point directly, in a process of its own")
+    path = [os.path.join(_ROOT, "src"), _ROOT, os.environ.get("PYTHONPATH", "")]
+    return dict(os.environ,
+                XLA_FLAGS=f"--xla_force_host_platform_device_count={n_devices}",
+                PYTHONPATH=os.pathsep.join(path).rstrip(os.pathsep))
 
 
 @functools.lru_cache(maxsize=4)

@@ -22,7 +22,11 @@ import time
 # Script-friendly bootstrap: `python benchmarks/run.py` puts benchmarks/ on
 # sys.path but neither the repo root (for `import benchmarks`) nor src (for
 # `import repro`); add both so the module works as script and as -m target.
-_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# benchmarks/ itself comes off the path: its compression.py would shadow
+# the module of that name JAX imports.
+_HERE = os.path.dirname(os.path.abspath(__file__))
+_ROOT = os.path.dirname(_HERE)
+sys.path[:] = [p for p in sys.path if os.path.abspath(p or ".") != _HERE]
 for _p in (_ROOT, os.path.join(_ROOT, "src")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
@@ -106,6 +110,8 @@ def main(argv=None):
 
     if args.list:
         return list_harnesses()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     n_docs = 192 if args.quick else 384
     n_q = 6 if args.quick else 12

@@ -227,6 +227,8 @@ def run(shard_counts=(1, 4, 16), n_docs: int = 93, B: int = 8, N: int = 16,
         out: str = "BENCH_sharded.json") -> Dict:
     """Spawn one subprocess per shard count (each pins its own XLA host
     device count BEFORE importing jax) and collect the rows."""
+    from benchmarks.common import cpu_worker_env
+    envs = {s: cpu_worker_env(s) for s in shard_counts}
     rows = {}
     for s in shard_counts:
         cmd = [sys.executable, "-m", "benchmarks.sharded_serving",
@@ -235,14 +237,8 @@ def run(shard_counts=(1, 4, 16), n_docs: int = 93, B: int = 8, N: int = 16,
                "--doc-len", str(L), "--dim", str(M), "--topk", str(k),
                "--alpha-ef", str(alpha_ef), "--batches", str(n_batches),
                "--seed", str(seed)]
-        env = dict(os.environ,
-                   XLA_FLAGS=f"--xla_force_host_platform_device_count={s}",
-                   JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"),
-                   PYTHONPATH=os.pathsep.join(
-                       [os.path.join(_ROOT, "src"), _ROOT,
-                        os.environ.get("PYTHONPATH", "")]).rstrip(os.pathsep))
         proc = subprocess.run(cmd, capture_output=True, text=True,
-                              timeout=900, cwd=_ROOT, env=env)
+                              timeout=900, cwd=_ROOT, env=envs[s])
         if proc.returncode != 0:
             raise RuntimeError(f"{s}-shard worker failed:\n"
                                f"{proc.stderr[-3000:]}")

@@ -5,6 +5,7 @@ point.
   PYTHONPATH=src python examples/calibration_sweep.py
 """
 from benchmarks.common import bench_dataset, frontier_bandit
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -19,4 +20,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

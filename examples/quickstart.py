@@ -12,6 +12,7 @@ from repro.configs.base import BanditConfig
 from repro.data.synthetic import make_retrieval_dataset
 from repro.retrieval.index import build_index
 from repro.retrieval.pipeline import serve_queries
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -36,4 +37,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.data.synthetic import make_retrieval_dataset
 from repro.serve import EngineConfig, Request, RetrievalEngine
+from repro.launch.compile_cache import enable_compile_cache
 
 
 class SimClock:
@@ -110,4 +111,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -14,6 +14,7 @@ from repro.models.transformer import init_lm
 from repro.train.optimizer import adamw, cosine_schedule
 from repro.train.train_step import TrainState, make_lm_train_step
 from repro.train.trainer import Trainer, TrainerConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -45,4 +46,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

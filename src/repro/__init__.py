@@ -1,6 +1,3 @@
 """repro: Col-Bandit late-interaction retrieval framework (JAX/Pallas)."""
-from repro import _compat
-
-_compat.install()
 
 __version__ = "0.1.0"

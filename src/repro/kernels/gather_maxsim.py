@@ -9,7 +9,8 @@ The doc/query gathers happen at the XLA level (cheap dynamic-slice / take on
 small N); the kernel then runs a dense batched (B, L, M) x (B, G, M)
 matmul-max with L tiled through VMEM. FLOPs = B * G * L * M * 2 exactly —
 the bandit's savings are realized 1:1, with zero tile waste from irregular
-reveal patterns.
+reveal patterns. With a quantized corpus the gather moves int8 payload +
+sidecars only; dequantization happens per VMEM block inside the kernel.
 """
 from __future__ import annotations
 
@@ -20,58 +21,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.quant import QuantTokens, corpus_take, dequant_block
+from repro.kernels.maxsim import _NEG, corpus_specs, load_tile, mask_sims
+from repro.kernels.quant import corpus_take
 
-_NEG = -3e38  # python float: jnp constants would be captured as kernel consts
 
-
-def _gather_maxsim_kernel(e_ref, m_ref, q_ref, out_ref, acc_ref, *,
-                          n_l_blocks):
-    l = pl.program_id(1)
-
+def reveal_tile(e_refs, m_ref, q_ref, acc_ref, l):
+    """One L step of a gathered reveal: (BB, BL, M) . (BB, G, M) batched
+    over BB -> masked running max in the (BB, G) accumulator."""
     @pl.when(l == 0)
     def _init():
         acc_ref[...] = jnp.full_like(acc_ref, _NEG)
 
-    e = e_ref[...].astype(jnp.float32)     # (BB, BL, M)
+    e = load_tile(e_refs)                  # (BB, BL, M)
     q = q_ref[...].astype(jnp.float32)     # (BB, G, M)
-    mask = m_ref[...]                      # (BB, BL)
-    # batched (BB): (BL, M) . (G, M) -> (BL, G)
     sims = jax.lax.dot_general(
         e, q, (((2,), (2,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
-    sims = jnp.where(mask[:, :, None], sims, _NEG)
+    sims = mask_sims(sims, m_ref[...])
     acc_ref[...] = jnp.maximum(acc_ref[...], jnp.max(sims, axis=1))
 
-    @pl.when(l == n_l_blocks - 1)
-    def _done():
-        out_ref[...] = acc_ref[...]
 
-
-def _gather_maxsim_q_kernel(*refs, n_l_blocks, residual):
-    """Quantized-corpus variant: the XLA-level doc gather moved int8 bytes
-    (plus tiny sidecars); rows are reconstructed per VMEM block here."""
-    if residual:
-        e_ref, s_ref, c_ref, cb_ref, m_ref, q_ref, out_ref, acc_ref = refs
-    else:
-        e_ref, s_ref, m_ref, q_ref, out_ref, acc_ref = refs
-        c_ref = cb_ref = None
+def _gather_maxsim_kernel(*refs, n_e, n_l_blocks):
+    m_ref, q_ref, out_ref, acc_ref = refs[n_e:]
     l = pl.program_id(1)
-
-    @pl.when(l == 0)
-    def _init():
-        acc_ref[...] = jnp.full_like(acc_ref, _NEG)
-
-    e = dequant_block(e_ref[...], s_ref[...],
-                      None if c_ref is None else c_ref[...],
-                      None if cb_ref is None else cb_ref[...])
-    q = q_ref[...].astype(jnp.float32)     # (BB, G, M)
-    mask = m_ref[...]                      # (BB, BL)
-    sims = jax.lax.dot_general(
-        e, q, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    sims = jnp.where(mask[:, :, None], sims, _NEG)
-    acc_ref[...] = jnp.maximum(acc_ref[...], jnp.max(sims, axis=1))
+    reveal_tile(refs[:n_e], m_ref, q_ref, acc_ref, l)
 
     @pl.when(l == n_l_blocks - 1)
     def _done():
@@ -84,11 +58,7 @@ def gather_maxsim(doc_embs: jax.Array, doc_tok_mask: jax.Array,
                   queries: jax.Array, doc_idx: jax.Array, tok_idx: jax.Array,
                   *, block_b: int = 8, block_l: int = 256,
                   interpret: bool = False) -> jax.Array:
-    """out (B, G) — MaxSim values for the selected cells.
-
-    With a quantized corpus the gather moves int8 payload + sidecars only;
-    dequantization happens per VMEM block inside the kernel.
-    """
+    """out (B, G) — MaxSim values for the selected cells."""
     B, G = tok_idx.shape
     L, M = doc_embs.shape[1], doc_embs.shape[2]
     e = corpus_take(doc_embs, doc_idx, axis=0)         # (B, L, M)
@@ -104,47 +74,17 @@ def gather_maxsim(doc_embs: jax.Array, doc_tok_mask: jax.Array,
             "through repro.kernels.ops.gather_maxsim_op, which pads both "
             "axes (and documents the padding contract).")
     n_l_blocks = L // bl
-
-    grid = (B // bb, n_l_blocks)
-    if isinstance(e, QuantTokens):
-        residual = e.codes is not None
-        in_specs = [
-            pl.BlockSpec((bb, bl, M), lambda i, l: (i, l, 0)),
-            pl.BlockSpec((bb, bl), lambda i, l: (i, l)),
-        ]
-        operands = [e.data, e.scales]
-        if residual:
-            kc = e.codebook.shape[0]
-            in_specs += [
-                pl.BlockSpec((bb, bl), lambda i, l: (i, l)),
-                pl.BlockSpec((kc, M), lambda i, l: (0, 0)),
-            ]
-            operands += [e.codes, e.codebook]
-        in_specs += [
-            pl.BlockSpec((bb, bl), lambda i, l: (i, l)),
-            pl.BlockSpec((bb, G, M), lambda i, l: (i, 0, 0)),
-        ]
-        operands += [m, q]
-        return pl.pallas_call(
-            functools.partial(_gather_maxsim_q_kernel, n_l_blocks=n_l_blocks,
-                              residual=residual),
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((bb, G), lambda i, l: (i, 0)),
-            out_shape=jax.ShapeDtypeStruct((B, G), jnp.float32),
-            scratch_shapes=[pltpu.VMEM((bb, G), jnp.float32)],
-            interpret=interpret,
-        )(*operands)
+    row = pl.BlockSpec((bb, bl), lambda i, l: (i, l))
+    operands, in_specs = corpus_specs(
+        e, pl.BlockSpec((bb, bl, M), lambda i, l: (i, l, 0)), row)
     return pl.pallas_call(
-        functools.partial(_gather_maxsim_kernel, n_l_blocks=n_l_blocks),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bb, bl, M), lambda i, l: (i, l, 0)),
-            pl.BlockSpec((bb, bl), lambda i, l: (i, l)),
-            pl.BlockSpec((bb, G, M), lambda i, l: (i, 0, 0)),
-        ],
+        functools.partial(_gather_maxsim_kernel, n_e=len(operands),
+                          n_l_blocks=n_l_blocks),
+        grid=(B // bb, n_l_blocks),
+        in_specs=in_specs + [row, pl.BlockSpec((bb, G, M),
+                                               lambda i, l: (i, 0, 0))],
         out_specs=pl.BlockSpec((bb, G), lambda i, l: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, G), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bb, G), jnp.float32)],
         interpret=interpret,
-    )(e, m, q)
+    )(*operands, m, q)

@@ -5,10 +5,13 @@ kernel SKIPS the MXU work for every inactive tile via ``pl.when`` — compute
 is saved at tile granularity, not just masked out. Inactive output tiles are
 written as exact 0 on the first L step so the output is fully defined.
 
-tile_mask has shape (N/BN, T/BT): one bool per output tile. The static-budget
-baselines (Doc-TopMargin with tile-aligned reveals) and the bulk reranking
-path use this; the round-based bandit uses the gather kernel instead
-(``gather_maxsim``) because its reveal sets are irregular.
+tile_mask has shape (N/BN, T/BT): one bool per output tile, scalar-
+prefetched into SMEM. The output is produced as (T/BT, N, BT) — each block's
+last two dims (BN, BT) then meet Mosaic's (8, 128)-or-whole-dim rule even
+for a narrow semantic BT — and transposed back to (N, T) by XLA. The
+static-budget baselines (Doc-TopMargin with tile-aligned reveals) and the
+bulk reranking path use this; the round-based bandit uses the gather kernel
+instead (``gather_maxsim``) because its reveal sets are irregular.
 """
 from __future__ import annotations
 
@@ -19,15 +22,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.quant import QuantTokens, dequant_block
-
-_NEG = -3e38  # python float: jnp constants would be captured as kernel consts
+from repro.kernels.maxsim import _NEG, corpus_specs, load_tile, mask_sims
 
 
-def _masked_maxsim_kernel(mask_ref, e_ref, m_ref, q_ref, out_ref, acc_ref, *,
-                          n_l_blocks):
+def _masked_maxsim_kernel(tm_ref, *refs, n_e, n_l_blocks):
+    e_refs = refs[:n_e]
+    m_ref, q_ref, out_ref, acc_ref = refs[n_e:]
     l = pl.program_id(2)
-    active = mask_ref[0, 0]
+    active = tm_ref[pl.program_id(0), pl.program_id(1)] != 0
 
     @pl.when(jnp.logical_not(active) & (l == 0))
     def _skip():
@@ -39,52 +41,13 @@ def _masked_maxsim_kernel(mask_ref, e_ref, m_ref, q_ref, out_ref, acc_ref, *,
         def _init():
             acc_ref[...] = jnp.full_like(acc_ref, _NEG)
 
-        e = e_ref[...].astype(jnp.float32)
+        e = load_tile(e_refs)
         q = q_ref[...].astype(jnp.float32)
-        tok_mask = m_ref[...]
         sims = jax.lax.dot_general(
             e, q, (((2,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
-        sims = jnp.where(tok_mask[:, :, None], sims, _NEG)
-        acc_ref[...] = jnp.maximum(acc_ref[...], jnp.max(sims, axis=1))
-
-        @pl.when(l == n_l_blocks - 1)
-        def _done():
-            out_ref[...] = acc_ref[...]
-
-
-def _masked_maxsim_q_kernel(*refs, n_l_blocks, residual):
-    """Quantized-corpus variant: identical tile skipping, but the embedding
-    block is reconstructed from int8 (+ sidecars) in VMEM inside the active
-    branch — inactive tiles skip the dequant work too."""
-    if residual:
-        (mask_ref, e_ref, s_ref, c_ref, cb_ref, m_ref, q_ref, out_ref,
-         acc_ref) = refs
-    else:
-        mask_ref, e_ref, s_ref, m_ref, q_ref, out_ref, acc_ref = refs
-        c_ref = cb_ref = None
-    l = pl.program_id(2)
-    active = mask_ref[0, 0]
-
-    @pl.when(jnp.logical_not(active) & (l == 0))
-    def _skip():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    @pl.when(active)
-    def _compute():
-        @pl.when(l == 0)
-        def _init():
-            acc_ref[...] = jnp.full_like(acc_ref, _NEG)
-
-        e = dequant_block(e_ref[...], s_ref[...],
-                          None if c_ref is None else c_ref[...],
-                          None if cb_ref is None else cb_ref[...])
-        q = q_ref[...].astype(jnp.float32)
-        tok_mask = m_ref[...]
-        sims = jax.lax.dot_general(
-            e, q, (((2,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        sims = jnp.where(tok_mask[:, :, None], sims, _NEG)
+        sims = mask_sims(sims, m_ref[...])
         acc_ref[...] = jnp.maximum(acc_ref[...], jnp.max(sims, axis=1))
 
         @pl.when(l == n_l_blocks - 1)
@@ -112,49 +75,24 @@ def masked_maxsim(doc_embs: jax.Array, doc_tok_mask: jax.Array,
         raise ValueError(f"tile_mask must be (N//bn, T//bt)="
                          f"({N // bn},{T // bt}); got {tile_mask.shape}")
     n_l_blocks = L // bl
-
-    grid = (N // bn, T // bt, n_l_blocks)
-    if isinstance(doc_embs, QuantTokens):
-        residual = doc_embs.codes is not None
-        in_specs = [
-            pl.BlockSpec((1, 1), lambda i, j, l: (i, j)),
-            pl.BlockSpec((bn, bl, M), lambda i, j, l: (i, l, 0)),
-            pl.BlockSpec((bn, bl), lambda i, j, l: (i, l)),
-        ]
-        operands = [tile_mask, doc_embs.data, doc_embs.scales]
-        if residual:
-            kc = doc_embs.codebook.shape[0]
-            in_specs += [
-                pl.BlockSpec((bn, bl), lambda i, j, l: (i, l)),
-                pl.BlockSpec((kc, M), lambda i, j, l: (0, 0)),
-            ]
-            operands += [doc_embs.codes, doc_embs.codebook]
-        in_specs += [
-            pl.BlockSpec((bn, bl), lambda i, j, l: (i, l)),
-            pl.BlockSpec((bt, M), lambda i, j, l: (j, 0)),
-        ]
-        operands += [doc_tok_mask, queries]
-        return pl.pallas_call(
-            functools.partial(_masked_maxsim_q_kernel, n_l_blocks=n_l_blocks,
-                              residual=residual),
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((bn, bt), lambda i, j, l: (i, j)),
-            out_shape=jax.ShapeDtypeStruct((N, T), jnp.float32),
-            scratch_shapes=[pltpu.VMEM((bn, bt), jnp.float32)],
-            interpret=interpret,
-        )(*operands)
-    return pl.pallas_call(
-        functools.partial(_masked_maxsim_kernel, n_l_blocks=n_l_blocks),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, l: (i, j)),
-            pl.BlockSpec((bn, bl, M), lambda i, j, l: (i, l, 0)),
-            pl.BlockSpec((bn, bl), lambda i, j, l: (i, l)),
-            pl.BlockSpec((bt, M), lambda i, j, l: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((bn, bt), lambda i, j, l: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((N, T), jnp.float32),
+    row = pl.BlockSpec((bn, bl), lambda i, j, l, tm: (i, l))
+    operands, in_specs = corpus_specs(
+        doc_embs, pl.BlockSpec((bn, bl, M), lambda i, j, l, tm: (i, l, 0)),
+        row)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(N // bn, T // bt, n_l_blocks),
+        in_specs=in_specs + [row, pl.BlockSpec((bt, M),
+                                               lambda i, j, l, tm: (j, 0))],
+        out_specs=pl.BlockSpec((pl.squeezed, bn, bt),
+                               lambda i, j, l, tm: (j, i, 0)),
         scratch_shapes=[pltpu.VMEM((bn, bt), jnp.float32)],
+    )
+    h = pl.pallas_call(
+        functools.partial(_masked_maxsim_kernel, n_e=len(operands),
+                          n_l_blocks=n_l_blocks),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((T // bt, N, bt), jnp.float32),
         interpret=interpret,
-    )(tile_mask, doc_embs, doc_tok_mask, queries)
+    )(tile_mask.astype(jnp.int32), *operands, doc_tok_mask, queries)
+    return jnp.transpose(h, (1, 0, 2)).reshape(N, T)

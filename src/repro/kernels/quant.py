@@ -180,6 +180,7 @@ def dequant_block(data, scales, codes=None, codebook=None):
         cents = jax.lax.dot_general(
             one_hot, codebook.astype(jnp.float32),
             (((one_hot.ndim - 1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
         out = out + cents
     return out

@@ -25,10 +25,13 @@ This kernel fuses the gather -> score -> accumulate middle of the round:
 
 Grid: (F // block_b, L // block_l), L innermost. ``gather=True`` requires
 ``block_b == 1`` (one frontier row per step — the index map can only
-redirect a whole block); ``gather=False`` takes pre-gathered (F, L, M)
-rows and allows wider row blocks, which is the cheaper layout for the
-interpret-mode CI lane (trace time scales with grid size, and CPU has no
-HBM/VMEM distinction to exploit).
+redirect a whole block): only the embedding tile is gathered in-kernel,
+while the small per-row operands (token mask, quantization sidecars) are
+gathered by XLA into (F, 1, X) arrays whose squeezed (1, X) blocks meet
+Mosaic's (8, 128)-or-whole-dim block rule. ``gather=False`` takes
+pre-gathered (F, L, M) rows and allows wider row blocks, which is the
+cheaper layout for the interpret-mode CI lane (trace time scales with grid
+size, and CPU has no HBM/VMEM distinction to exploit).
 
 Stats live in the first ``STATS_USED`` lanes of a ``STATS_W``-wide output
 row (lane-padded so the store stays tile-aligned on TPU).
@@ -42,32 +45,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.quant import QuantTokens, corpus_take, dequant_block
-
-_NEG = -3e38  # python float: jnp constants would be captured as kernel consts
+from repro.kernels.gather_maxsim import reveal_tile
+from repro.kernels.maxsim import corpus_specs
 
 STATS_W = 8        # lane-padded stats row width
 STATS_USED = 3     # [d_count, d_total, d_total_sq]
 
 
-def _fused_reveal_kernel(doc_idx_ref, e_ref, m_ref, q_ref, new_ref,
-                         vals_ref, stats_ref, acc_ref, *, n_l_blocks):
+def _fused_reveal_kernel(doc_idx_ref, *refs, n_e, n_l_blocks):
     del doc_idx_ref  # consumed by the index maps, not the body
+    m_ref, q_ref, new_ref, vals_ref, stats_ref, acc_ref = refs[n_e:]
     l = pl.program_id(1)
-
-    @pl.when(l == 0)
-    def _init():
-        acc_ref[...] = jnp.full_like(acc_ref, _NEG)
-
-    e = e_ref[...].astype(jnp.float32)     # (BB, BL, M)
-    q = q_ref[...].astype(jnp.float32)     # (BB, G, M)
-    mask = m_ref[...]                      # (BB, BL)
-    # batched (BB): (BL, M) . (G, M) -> (BL, G)
-    sims = jax.lax.dot_general(
-        e, q, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    sims = jnp.where(mask[:, :, None], sims, _NEG)
-    acc_ref[...] = jnp.maximum(acc_ref[...], jnp.max(sims, axis=1))
+    reveal_tile(refs[:n_e], m_ref, q_ref, acc_ref, l)
 
     @pl.when(l == n_l_blocks - 1)
     def _done():
@@ -81,55 +70,6 @@ def _fused_reveal_kernel(doc_idx_ref, e_ref, m_ref, q_ref, new_ref,
         # vm * v (not nf * v * v): a 0 * inf from an all-masked document's
         # _NEG sentinel squaring out of f32 range would poison the row
         # with NaN; where-masking first keeps dead lanes exactly 0.
-        d_sq = jnp.sum(vm * v, axis=-1)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (v.shape[0], STATS_W), 1)
-        stats_ref[...] = jnp.where(
-            lane == 0, d_n[:, None],
-            jnp.where(lane == 1, d_tot[:, None],
-                      jnp.where(lane == 2, d_sq[:, None], 0.0)))
-
-
-def _fused_reveal_q_kernel(doc_idx_ref, *refs, n_l_blocks, residual):
-    """Quantized-corpus fused reveal: the scalar-prefetched index maps DMA
-    the selected doc's int8 payload block (plus scale / centroid-id rows)
-    straight from the compressed resident corpus — HBM only ever moves
-    compressed bytes, and the f32 row exists solely in VMEM between the
-    dequant and the dot."""
-    del doc_idx_ref  # consumed by the index maps, not the body
-    if residual:
-        (e_ref, s_ref, c_ref, cb_ref, m_ref, q_ref, new_ref, vals_ref,
-         stats_ref, acc_ref) = refs
-    else:
-        e_ref, s_ref, m_ref, q_ref, new_ref, vals_ref, stats_ref, \
-            acc_ref = refs
-        c_ref = cb_ref = None
-    l = pl.program_id(1)
-
-    @pl.when(l == 0)
-    def _init():
-        acc_ref[...] = jnp.full_like(acc_ref, _NEG)
-
-    e = dequant_block(e_ref[...], s_ref[...],
-                      None if c_ref is None else c_ref[...],
-                      None if cb_ref is None else cb_ref[...])
-    q = q_ref[...].astype(jnp.float32)     # (BB, G, M)
-    mask = m_ref[...]                      # (BB, BL)
-    sims = jax.lax.dot_general(
-        e, q, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    sims = jnp.where(mask[:, :, None], sims, _NEG)
-    acc_ref[...] = jnp.maximum(acc_ref[...], jnp.max(sims, axis=1))
-
-    @pl.when(l == n_l_blocks - 1)
-    def _done():
-        v = acc_ref[...]                   # (BB, G)
-        vals_ref[...] = v
-        new = new_ref[...]                 # (BB, G) bool — fresh cells only
-        nf = new.astype(jnp.float32)
-        vm = jnp.where(new, v, 0.0)
-        d_n = jnp.sum(nf, axis=-1)         # (BB,)
-        d_tot = jnp.sum(vm, axis=-1)
-        # vm * v, not nf * v * v — see _fused_reveal_kernel
         d_sq = jnp.sum(vm * v, axis=-1)
         lane = jax.lax.broadcasted_iota(jnp.int32, (v.shape[0], STATS_W), 1)
         stats_ref[...] = jnp.where(
@@ -171,67 +111,46 @@ def fused_reveal(doc_embs: jax.Array, doc_tok_mask: jax.Array,
             "through repro.kernels.ops.fused_reveal_op, which pads both "
             "axes (and documents the padding contract).")
     n_l_blocks = L // bl
-
+    q_spec = pl.BlockSpec((bb, G, M), lambda i, l, di: (i, 0, 0))
     if gather:
-        e_spec = pl.BlockSpec((bb, bl, M), lambda i, l, di: (di[i], l, 0))
-        m_spec = pl.BlockSpec((bb, bl), lambda i, l, di: (di[i], l))
-        row_spec = pl.BlockSpec((bb, bl), lambda i, l, di: (di[i], l))
+        # Row operands become (F, 1, X): a squeezed leading block dim leaves
+        # the kernel (1, X) tiles whose last two dims equal the array's.
+        sq = pl.squeezed
+        unit = lambda x: x.reshape(x.shape[0], 1, x.shape[-1])
+        sel = lambda x: unit(jnp.take(x, doc_idx, axis=0))
+        row = pl.BlockSpec((sq, 1, bl), lambda i, l, di: (i, 0, l))
+        cell = pl.BlockSpec((sq, 1, G), lambda i, l, di: (i, 0, 0))
+        stat = pl.BlockSpec((sq, 1, STATS_W), lambda i, l, di: (i, 0, 0))
+        operands, in_specs = corpus_specs(
+            doc_embs, pl.BlockSpec((1, bl, M), lambda i, l, di: (di[i], l, 0)),
+            row)
+        operands[1:3] = [sel(x) for x in operands[1:3]]  # scales, codes
+        operands += [sel(doc_tok_mask), q_sel, unit(new_mask)]
+        out_shape = [jax.ShapeDtypeStruct((F, 1, G), jnp.float32),
+                     jax.ShapeDtypeStruct((F, 1, STATS_W), jnp.float32)]
     else:
-        e_spec = pl.BlockSpec((bb, bl, M), lambda i, l, di: (i, l, 0))
-        m_spec = pl.BlockSpec((bb, bl), lambda i, l, di: (i, l))
-        row_spec = pl.BlockSpec((bb, bl), lambda i, l, di: (i, l))
-
-    if isinstance(doc_embs, QuantTokens):
-        residual = doc_embs.codes is not None
-        in_specs = [e_spec, row_spec]
-        operands = [doc_embs.data, doc_embs.scales]
-        if residual:
-            kc = doc_embs.codebook.shape[0]
-            in_specs += [row_spec,
-                         pl.BlockSpec((kc, M), lambda i, l, di: (0, 0))]
-            operands += [doc_embs.codes, doc_embs.codebook]
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(F // bb, n_l_blocks),
-            in_specs=in_specs + [
-                m_spec,
-                pl.BlockSpec((bb, G, M), lambda i, l, di: (i, 0, 0)),
-                pl.BlockSpec((bb, G), lambda i, l, di: (i, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((bb, G), lambda i, l, di: (i, 0)),
-                pl.BlockSpec((bb, STATS_W), lambda i, l, di: (i, 0)),
-            ],
-            scratch_shapes=[pltpu.VMEM((bb, G), jnp.float32)],
-        )
-        return pl.pallas_call(
-            functools.partial(_fused_reveal_q_kernel, n_l_blocks=n_l_blocks,
-                              residual=residual),
-            grid_spec=grid_spec,
-            out_shape=[jax.ShapeDtypeStruct((F, G), jnp.float32),
-                       jax.ShapeDtypeStruct((F, STATS_W), jnp.float32)],
-            interpret=interpret,
-        )(doc_idx, *operands, doc_tok_mask, q_sel, new_mask)
-
+        row = pl.BlockSpec((bb, bl), lambda i, l, di: (i, l))
+        cell = pl.BlockSpec((bb, G), lambda i, l, di: (i, 0))
+        stat = pl.BlockSpec((bb, STATS_W), lambda i, l, di: (i, 0))
+        operands, in_specs = corpus_specs(
+            doc_embs, pl.BlockSpec((bb, bl, M), lambda i, l, di: (i, l, 0)),
+            row)
+        operands += [doc_tok_mask, q_sel, new_mask]
+        out_shape = [jax.ShapeDtypeStruct((F, G), jnp.float32),
+                     jax.ShapeDtypeStruct((F, STATS_W), jnp.float32)]
+    n_e = len(operands) - 3
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(F // bb, n_l_blocks),
-        in_specs=[
-            e_spec,
-            m_spec,
-            pl.BlockSpec((bb, G, M), lambda i, l, di: (i, 0, 0)),
-            pl.BlockSpec((bb, G), lambda i, l, di: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bb, G), lambda i, l, di: (i, 0)),
-            pl.BlockSpec((bb, STATS_W), lambda i, l, di: (i, 0)),
-        ],
+        in_specs=in_specs + [row, q_spec, cell],
+        out_specs=[cell, stat],
         scratch_shapes=[pltpu.VMEM((bb, G), jnp.float32)],
     )
-    return pl.pallas_call(
-        functools.partial(_fused_reveal_kernel, n_l_blocks=n_l_blocks),
+    vals, stats = pl.pallas_call(
+        functools.partial(_fused_reveal_kernel, n_e=n_e,
+                          n_l_blocks=n_l_blocks),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((F, G), jnp.float32),
-                   jax.ShapeDtypeStruct((F, STATS_W), jnp.float32)],
+        out_shape=out_shape,
         interpret=interpret,
-    )(doc_idx, doc_embs, doc_tok_mask, q_sel, new_mask)
+    )(doc_idx, *operands)
+    return vals.reshape(F, G), stats.reshape(F, STATS_W)

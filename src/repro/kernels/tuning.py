@@ -37,10 +37,12 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 # before tuning existed, except maxsim's block_t: the old ``block_t=0``
 # resolved to the FULL query-token axis, which blows the documented VMEM
 # tile budget for unbucketed large-T calls; 128 is the one-lane-tile cap
-# the kernel's tile math is written for (kernels/maxsim.py header).
+# the kernel's tile math is written for (kernels/maxsim.py header). The
+# batched op shares that cap: a narrower BT than the bucketed T would make
+# its (BN, BT) output block illegal on the chip.
 DEFAULTS: Dict[str, Dict[str, int]] = {
     "maxsim": {"block_n": 8, "block_t": 128, "block_l": 256},
-    "maxsim_batch": {"block_n": 8, "block_t": 8, "block_l": 128},
+    "maxsim_batch": {"block_n": 8, "block_t": 128, "block_l": 128},
     "masked_maxsim": {"block_l": 256},
     "gather_maxsim": {"block_b": 8, "block_l": 256},
     "fused_reveal": {"block_b": 8, "block_l": 256},
@@ -50,33 +52,17 @@ DEFAULTS: Dict[str, Dict[str, int]] = {
 # one executable per candidate, and warmup budgets are real. Candidates
 # whose block exceeds the (padded) dimension collapse to the clamped
 # config, so duplicates are pruned against the launch dims before timing.
-# The maxsim/maxsim_batch grids were widened after BENCH_kernels.json
-# showed speedups pinned at 1.0: at bucketed serving shapes (T<=64,
-# N<=32) the old 3-4 point grids clamped every candidate onto the
-# default, so there was nothing to win. The same grids serve the
-# quantized (int8/residual) launches — those buckets carry an FMT dim
-# (see ops._fmt_dims), so each format records its own winner per shape.
+# Every candidate obeys Mosaic's block rule (last two block dims divide by
+# (8, 128) or equal the array's): the token-mask blocks put BL on lanes,
+# so BL is a multiple of 128, and BT stays at the 128 cap, which clamps to
+# the whole (bucketed) T axis. The same grids serve the quantized
+# (int8/residual) launches — those buckets carry an FMT dim (see
+# ops._fmt_dims), so each format records its own winner per shape.
+_ROW_TILES = [{"block_n": n, "block_t": 128, "block_l": l}
+              for n in (8, 16, 32) for l in (256, 128)]
 CANDIDATES: Dict[str, List[Dict[str, int]]] = {
-    "maxsim": [
-        {"block_n": 8, "block_t": 128, "block_l": 256},
-        {"block_n": 8, "block_t": 128, "block_l": 128},
-        {"block_n": 16, "block_t": 128, "block_l": 128},
-        {"block_n": 16, "block_t": 128, "block_l": 256},
-        {"block_n": 32, "block_t": 128, "block_l": 128},
-        {"block_n": 8, "block_t": 64, "block_l": 256},
-        {"block_n": 8, "block_t": 32, "block_l": 256},
-        {"block_n": 16, "block_t": 64, "block_l": 128},
-        {"block_n": 32, "block_t": 32, "block_l": 128},
-    ],
-    "maxsim_batch": [
-        {"block_n": 8, "block_t": 8, "block_l": 128},
-        {"block_n": 8, "block_t": 16, "block_l": 128},
-        {"block_n": 16, "block_t": 16, "block_l": 128},
-        {"block_n": 16, "block_t": 8, "block_l": 64},
-        {"block_n": 8, "block_t": 16, "block_l": 64},
-        {"block_n": 4, "block_t": 16, "block_l": 128},
-        {"block_n": 16, "block_t": 16, "block_l": 64},
-    ],
+    "maxsim": _ROW_TILES,
+    "maxsim_batch": _ROW_TILES,
     "gather_maxsim": [
         {"block_b": 8, "block_l": 256},
         {"block_b": 16, "block_l": 128},

@@ -24,6 +24,10 @@ import jax
 import jax.numpy as jnp
 
 _NEG = jnp.float32(-3e38)
+# Docs per stage-1 scan step: the (T, chunk*L) f32 similarity block is
+# 32 MiB per query at T=32, L=128, so a vmapped batch of 8 stays near 1.3 GiB
+# of temp next to a resident index.
+STAGE1_CHUNK_DOCS = 2048
 
 
 class CandidateSet(NamedTuple):
@@ -40,8 +44,48 @@ class CandidateSet(NamedTuple):
         return jnp.sum(self.doc_mask)
 
 
+def token_topk(index_embs, index_mask, query, kprime: int, chunk_docs: int):
+    """Per-query-token top-k' over the index's valid token rows, scanned
+    ``chunk_docs`` documents at a time with a running top-k' — the
+    (T, C*L) similarity matrix never exists, so a full-size index fits next
+    to its scan. Ties keep the lower token position, exactly as one top_k
+    over the whole index does (the running list is merged first).
+    Returns (values (T, k'), owning doc ids (T, k'))."""
+    C, L, M = index_embs.shape
+    q = query.astype(jnp.float32)
+
+    def chunk_topk(embs, mask, start):
+        n = embs.shape[0]
+        sims = q @ embs.reshape(n * L, M).astype(jnp.float32).T    # (T, n*L)
+        sims = jnp.where(mask.reshape(-1)[None, :], sims, _NEG)
+        vals, idx = jax.lax.top_k(sims, min(kprime, n * L))
+        return vals, start + idx // L
+
+    def merge(best, new):
+        vals, pos = jax.lax.top_k(jnp.concatenate([best[0], new[0]], 1),
+                                  kprime)
+        docs = jnp.concatenate([best[1], new[1]], 1)
+        return vals, jnp.take_along_axis(docs, pos, axis=1)
+
+    chunk = min(max(chunk_docs, -(-kprime // L)), C)
+    n_full, rem = divmod(C, chunk)
+    best = chunk_topk(index_embs[:chunk], index_mask[:chunk], 0)
+    if n_full > 1:
+        def body(best, start):
+            e = jax.lax.dynamic_slice_in_dim(index_embs, start, chunk)
+            m = jax.lax.dynamic_slice_in_dim(index_mask, start, chunk)
+            return merge(best, chunk_topk(e, m, start)), None
+        best, _ = jax.lax.scan(
+            body, best, jnp.arange(1, n_full, dtype=jnp.int32) * chunk)
+    if rem:
+        tail = n_full * chunk
+        best = merge(best, chunk_topk(index_embs[tail:], index_mask[tail:],
+                                      tail))
+    return best
+
+
 @functools.partial(jax.jit, static_argnames=("kprime", "max_candidates",
-                                             "support"))
+                                             "support", "chunk_docs"))
 def generate_candidates(
     index_embs: jax.Array,      # (C, L, M)
     index_mask: jax.Array,      # (C, L)
@@ -51,18 +95,24 @@ def generate_candidates(
     kprime: int = 10,
     max_candidates: int = 256,
     support: Tuple[float, float] = (0.0, 1.0),
+    chunk_docs: int = STAGE1_CHUNK_DOCS,
 ) -> CandidateSet:
-    C, L, M = index_embs.shape
-    T = query.shape[0]
+    C, L, _ = index_embs.shape
     kprime = min(kprime, C * L)   # a tiny shard can't yield k' neighbors
-    toks = index_embs.reshape(C * L, M)
-    owner = jnp.repeat(jnp.arange(C, dtype=jnp.int32), L)
-    valid = index_mask.reshape(-1)
+    top_vals, hit_docs = token_topk(index_embs, index_mask, query, kprime,
+                                     chunk_docs)                  # (T, k')
+    return candidates_from_hits(top_vals, hit_docs, C, quota,
+                                max_candidates=max_candidates,
+                                support=support)
 
-    sims = query.astype(jnp.float32) @ toks.astype(jnp.float32).T  # (T, C*L)
-    sims = jnp.where(valid[None, :], sims, _NEG)
-    top_vals, top_idx = jax.lax.top_k(sims, kprime)                # (T, k')
-    hit_docs = jnp.take(owner, top_idx)                            # (T, k')
+
+def candidates_from_hits(top_vals, hit_docs, n_docs: int, quota=None, *,
+                         max_candidates: int,
+                         support: Tuple[float, float]) -> CandidateSet:
+    """Eq. 15 candidate set from per-token top-k' hits (values and owning
+    doc ids, (T, k') each, best first) over an ``n_docs``-document index."""
+    C = n_docs
+    T, kprime = top_vals.shape
     s_kprime = top_vals[:, kprime - 1]
 
     # Candidate set = union of hit docs. If the union exceeds

@@ -123,11 +123,27 @@ def validate_quotas(quotas: np.ndarray, valid_docs: np.ndarray, *,
             "n_local or lower n_total")
 
 
+@jax.jit
+def _pooled_units(embs, mask):
+    """Unit-normalized mean of each doc's valid token rows (C, M) f32, and
+    whether the doc has any valid token (C,). Runs where the corpus lives,
+    so only the (C, M) pooled rows ever reach the host."""
+    mask = mask.astype(bool)
+    denom = jnp.maximum(mask.sum(1, keepdims=True), 1).astype(jnp.float32)
+    pooled = jnp.einsum("clm,cl->cm", embs, mask.astype(embs.dtype),
+                        precision=jax.lax.Precision.HIGHEST,
+                        preferred_element_type=jnp.float32) / denom
+    pooled /= jnp.maximum(jnp.linalg.norm(pooled, axis=-1, keepdims=True),
+                          1e-9)
+    return pooled, mask.any(1)
+
+
 def build_router(embs, mask, *, n_shards: int, docs_per_shard: int,
                  n_centroids: int = 8, n_iters: int = 10, seed: int = 0,
                  valid_docs: Optional[np.ndarray] = None) -> CentroidRouter:
-    """Build the centroid router at corpus-shard time (host numpy; this is
-    index construction, not the query hot path).
+    """Build the centroid router at corpus-shard time (doc pooling where
+    the corpus lives, k-means in host numpy; this is index construction,
+    not the query hot path).
 
     Spherical k-means (Lloyd, ``n_iters`` fixed iterations, deterministic
     under ``seed``) over the doc-pooled unit embeddings of every doc with
@@ -136,16 +152,12 @@ def build_router(embs, mask, *, n_shards: int, docs_per_shard: int,
     docs_per_shard — the contiguous-block placement ``shard_corpus``
     uses). Empty clusters keep their centroid and zero mass. Docs with no
     valid token carry no mass (they can never be stage-1 candidates)."""
-    embs = np.asarray(embs).astype(np.float32)
-    mask = np.asarray(mask, bool)
     C, _, M = embs.shape
     if valid_docs is None:
         valid_docs = np.clip(C - docs_per_shard * np.arange(n_shards),
                              0, docs_per_shard).astype(np.int32)
-    denom = np.maximum(mask.sum(1, keepdims=True), 1).astype(np.float32)
-    pooled = (embs * mask[:, :, None]).sum(1) / denom
-    pooled /= np.maximum(np.linalg.norm(pooled, axis=-1, keepdims=True), 1e-9)
-    ids = np.nonzero(mask.any(1))[0]
+    pooled, has_tok = (np.asarray(x) for x in _pooled_units(embs, mask))
+    ids = np.nonzero(has_tok)[0]
     k = int(max(min(n_centroids, len(ids)), 1))
     if len(ids) == 0:
         cents = np.zeros((k, M), np.float32)
@@ -318,11 +330,9 @@ def build_corpus(embs, mask, *, mesh: Optional[Mesh] = None,
                       docs_per_shard=sc.docs_per_shard,
                       valid_docs=sc.valid_docs, router=sc.router,
                       pooled=sc.pooled, fmt=sc.fmt)
-    host = np.asarray(embs)
-    dmask_h = np.asarray(mask, bool)
-    if host.ndim != 3 or dmask_h.ndim != 2 or host.shape[:2] != dmask_h.shape:
+    if embs.ndim != 3 or mask.ndim != 2 or embs.shape[:2] != mask.shape:
         raise ValueError("corpus must be (C, L, M) embs + (C, L) mask")
-    C = host.shape[0]
+    C = embs.shape[0]
     if corpus_format == "residual" and not n_centroids:
         n_centroids = 8  # the residual codebook IS the router's centroids
     router = None
@@ -331,15 +341,17 @@ def build_corpus(embs, mask, *, mesh: Optional[Mesh] = None,
                               n_centroids=n_centroids, n_iters=router_iters,
                               seed=router_seed)
     if corpus_format == "bf16":
+        # A device-resident corpus stays where it is: no host round trip
+        # and no second copy.
         dev = jnp.asarray(embs)
         if dev.dtype != jnp.bfloat16:
             dev = dev.astype(jnp.float32)
     else:
         codebook = (None if corpus_format != "residual"
                     else np.asarray(router.centroids, np.float32))
-        dev = corpus_asarray(quantize(host.astype(np.float32), corpus_format,
-                                      codebook=codebook))
-    return Corpus(embs=dev, mask=jnp.asarray(dmask_h, jnp.bool_), mesh=None,
+        dev = corpus_asarray(quantize(np.asarray(embs, np.float32),
+                                      corpus_format, codebook=codebook))
+    return Corpus(embs=dev, mask=jnp.asarray(mask, jnp.bool_), mesh=None,
                   n_docs=C, n_shards=1, docs_per_shard=C,
                   valid_docs=np.asarray([C], np.int32), router=router,
                   pooled=None if pooled is None

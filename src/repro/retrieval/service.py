@@ -36,7 +36,9 @@ from repro.core.frontier import (FrontierState, init_frontier_state,
 from repro.kernels.ops import (fused_reveal_op, gather_maxsim_op,
                                maxsim_batch_op)
 from repro.kernels.quant import QuantTokens, corpus_reshape
-from repro.retrieval.ann import generate_candidates
+from repro.launch.mesh import auto_axes
+from repro.retrieval.ann import (STAGE1_CHUNK_DOCS, candidates_from_hits,
+                                 generate_candidates, token_topk)
 from repro.retrieval.corpus import gather_tokens, route_mass, route_quotas
 from repro.retrieval.sharded import corpus_embs_spec
 
@@ -131,6 +133,28 @@ def _shard_global_ids(cand, c_loc, every, valid_docs=None):
     return jnp.where(ok, cand + shard_ix * c_loc, -1)
 
 
+def _exact_winners(c_embs, c_mask, q, cand, gids, bg, n_rev, won):
+    """Exact MaxSim of one shard's bandit winners, before the merge.
+
+    A shard's bandit score for a partly revealed doc is an estimate, and
+    estimates from different shards are not comparable: merging on them
+    picked the wrong global top-K (sharded top-5 overlap 0.77 against 0.94
+    on one device, same requests). Scoring the K winners exactly makes the
+    merge compare exact scores; the winners' cells the bandit had not
+    revealed yet are added to the reveal count.
+
+    cand (B, N) local doc rows, gids (B, N) their global ids (-1 invalid),
+    bg (B, K) the winners' global ids, n_rev (B,) the bandit's reveals,
+    won (B, K) the winners' revealed cells -> (scores (B, K), n_rev (B,))."""
+    pos = jnp.argmax(gids[:, None, :] == bg[:, :, None], axis=-1)
+    rows = jnp.where(bg >= 0, jnp.take_along_axis(cand, pos, axis=1), -1)
+    docs, dmask = gather_candidates(c_embs, c_mask, rows)
+    s = _local_maxsim_scores(docs, dmask, q)
+    s = jnp.where((bg >= 0) & jnp.isfinite(s), s, _NEG)
+    fresh = jnp.where(bg >= 0, q.shape[1] - won, 0)
+    return s, n_rev + jnp.sum(fresh, axis=1).astype(jnp.float32)
+
+
 def _merge_scorecards(scores, gids, every, topk):
     """All-gather per-shard scorecards and take the global top-K.
     The only cross-shard traffic in the corpus-resident flavors.
@@ -210,6 +234,7 @@ def make_rerank_dense_step(mesh: Mesh, *, topk: int = 10,
     ``corpus_format`` must match the resident corpus (``ShardedCorpus
     .fmt``) — shard_map in_specs are built before the operands arrive, so
     the quantized pytree structure has to be declared up front."""
+    mesh = auto_axes(mesh)
     every = tuple(mesh.axis_names)
     vd = None if valid_docs is None else jnp.asarray(valid_docs, jnp.int32)
     embs_spec = corpus_embs_spec(mesh, corpus_format)
@@ -248,7 +273,7 @@ def _bandit_one_query(cfg: BatchedConfig):
 
     Returns a closure (docs_q (N,L,M), dmask_q (N,L), q (T,M), cand_q (N,),
     a_q/b_q (N,T), key) -> (topk_scores (K,), topk_global_ids (K,),
-    coverage ()). The reveal op is the gathered MaxSim einsum; under vmap
+    coverage (), rounds (), winner_revealed (K,)). The reveal op is the gathered MaxSim einsum; under vmap
     every query pays the slowest query's round count."""
 
     def one_query(docs_q, dmask_q, q, cand_q, a_q, b_q, key):
@@ -264,7 +289,9 @@ def _bandit_one_query(cfg: BatchedConfig):
                                  doc_mask=cand_q >= 0)
         gids = jnp.where(jnp.take(cand_q, res.topk) >= 0,
                          jnp.take(cand_q, res.topk), -1)
-        return jnp.take(res.s_hat, res.topk), gids, res.coverage, res.rounds
+        won = jnp.sum(jnp.take(res.revealed, res.topk, axis=0), axis=1)
+        return (jnp.take(res.s_hat, res.topk), gids, res.coverage,
+                res.rounds, won)
 
     return one_query
 
@@ -280,13 +307,13 @@ def _vmapped_rerank(docs, dmask, queries, cand_ids, a, b, keys,
     in a result list."""
     del alpha_scale, round_cap
     _require_dense(docs, "the vmapped lockstep engine")
-    scores, gids, cov, rounds = jax.vmap(_bandit_one_query(cfg))(
+    scores, gids, cov, rounds, won = jax.vmap(_bandit_one_query(cfg))(
         docs, dmask, queries, cand_ids, a, b, keys)
     bad = ~jnp.isfinite(scores)
     quar = jnp.sum(bad).astype(jnp.float32)
     scores = jnp.where(bad, _NEG, scores)
     gids = jnp.where(bad, -1, gids)
-    return scores, gids, cov, _lockstep_stats(rounds, quar)
+    return scores, gids, cov, _lockstep_stats(rounds, quar), won
 
 
 def _lockstep_stats(rounds, quarantined):
@@ -324,7 +351,8 @@ def _pooled_rerank(docs, dmask, queries, cand_ids, a, b, keys,
     :func:`repro.core.frontier.run_pooled_bandit`); ``None`` is
     bit-identical to the pre-knob path. Returns (topk_scores (B, K),
     topk_global_ids (B, K), coverage (B,), stats (4,) = [frontier
-    occupancy, total rounds, lockstep waste, quarantined docs])."""
+    occupancy, total rounds, lockstep waste, quarantined docs],
+    winner_revealed (B, K) = revealed cells of each top-K doc)."""
     Bq, N, L, M = docs.shape
     T = queries.shape[1]
     stacked = corpus_reshape(docs, Bq * N)     # quantized: leaf-wise reshape
@@ -351,7 +379,9 @@ def _pooled_rerank(docs, dmask, queries, cand_ids, a, b, keys,
                        res.total_rounds.astype(jnp.float32),
                        res.lockstep_waste.astype(jnp.float32),
                        jnp.sum(res.quarantined).astype(jnp.float32)])
-    return scores, gids, res.coverage, stats
+    won = jnp.sum(jnp.take_along_axis(res.revealed, res.topk[:, :, None],
+                                      axis=1), axis=2)
+    return scores, gids, res.coverage, stats, won
 
 
 _RERANK_ENGINES = {
@@ -423,8 +453,8 @@ def make_rerank_bandit_step(mesh: Mesh, *, topk: int = 10,
         Returns (topk_global_ids (B, K), coverage (B,))."""
         B = queries.shape[0]
         keys = jax.random.split(jax.random.key(0), B)
-        _, gids, cov, _ = rerank(docs, dmask, queries, cand_ids, a, b,
-                                 keys, cfg)
+        _, gids, cov, _, _ = rerank(docs, dmask, queries, cand_ids, a, b,
+                                    keys, cfg)
         return gids, cov
 
     in_specs = (_gathered_docs_spec(every, corpus_format),  # docs (B,N,L,M)
@@ -477,6 +507,7 @@ def make_rerank_budgeted_step(mesh: Mesh, *, topk: int = 10,
     (Pallas on TPU) instead of contracting a gathered query einsum, so
     compiled FLOPs/bytes drop by ~G'/T — Col-Bandit's coverage savings
     made visible to the roofline."""
+    mesh = auto_axes(mesh)
     every = tuple(mesh.axis_names)
     vd = None if valid_docs is None else jnp.asarray(valid_docs, jnp.int32)
 
@@ -525,6 +556,7 @@ def make_rerank_two_phase_step(mesh: Mesh, *, topk: int = 10,
     (exact MaxSim on the survivors) lowers through ``maxsim_batch_op`` via
     ``_local_maxsim_scores``; phase 1 is a plain (b, N, M) matmul with no
     token axis to tile, so it stays jnp."""
+    mesh = auto_axes(mesh)
     every = tuple(mesh.axis_names)
     vd = None if valid_docs is None else jnp.asarray(valid_docs, jnp.int32)
 
@@ -644,7 +676,7 @@ def rerank_bandit_step(corpus_embs, corpus_mask, queries, cand_ids, a, b,
     docs, dmask = gather_candidates(corpus_embs, corpus_mask, cand_ids)
     keys = jax.random.split(key, queries.shape[0])
     return rerank(docs, dmask, queries, cand_ids, a, b, keys, cfg,
-                  alpha_scale=alpha_scale, round_cap=round_cap)
+                  alpha_scale=alpha_scale, round_cap=round_cap)[:4]
 
 
 def make_serving_step(flavor: str, *, topk: int = 10, alpha_ef: float = 0.3,
@@ -806,6 +838,7 @@ def make_sharded_serving_step(mesh: Mesh, flavor: str, *, topk: int = 10,
     the resident ``ShardedCorpus.fmt``: a quantized corpus arrives as a
     ``QuantTokens`` pytree, and the shard_map in_specs (declared here,
     before tracing) must mirror its structure leaf-for-leaf."""
+    mesh = auto_axes(mesh)
     every = tuple(mesh.axis_names)
     n_shards = 1
     for ax in every:
@@ -875,9 +908,10 @@ def make_sharded_serving_step(mesh: Mesh, flavor: str, *, topk: int = 10,
                 keys = jax.random.split(key, cand.shape[0])
                 kw = ({"alpha_scale": a_s, "round_cap": r_c} if knobs
                       else {})
-                best, bg, cov, stats_loc = rerank(
+                _, bg, cov, stats_loc, won = rerank(
                     docs, dmask, q, gids, a_l, b_l, keys, cfg, **kw)
-                n_rev = cov * n_cells
+                best, n_rev = _exact_winners(c_embs, c_mask, q, cand, gids,
+                                             bg, cov * n_cells, won)
 
             tot_rev = jax.lax.psum(n_rev, every)
             tot_cells = jax.lax.psum(n_cells, every)
@@ -961,6 +995,7 @@ def make_routed_serving_step(mesh: Mesh, flavor: str = "bandit", *,
 
     PRNG: ``fold_in(fold_in(key(base_seed), seed), shard_index)`` — same
     determinism contract as ``make_sharded_serving_step``."""
+    mesh = auto_axes(mesh)
     every = tuple(mesh.axis_names)
     n_shards = 1
     for ax in every:
@@ -1063,11 +1098,13 @@ def make_routed_serving_step(mesh: Mesh, flavor: str = "bandit", *,
                     n_known = jnp.sum(pr, axis=(1, 2)).astype(jnp.float32)
                 if knobs:
                     kw.update(alpha_scale=a_s, round_cap=r_c)
-                best, bg, cov, stats4 = rerank(
+                _, bg, cov, stats4, won = rerank(
                     docs, dmask, q, gids, a_l, b_l, keys, cfg, **kw)
                 # Reveal accounting: prereveal cells were free (stage 1
                 # already computed them), so they don't count as work.
-                n_rev = jnp.maximum(cov * n_cells - n_known, 0.0)
+                best, n_rev = _exact_winners(
+                    c_embs, c_mask, q, cand.doc_ids, gids, bg,
+                    jnp.maximum(cov * n_cells - n_known, 0.0), won)
 
             tot_rev = jax.lax.psum(n_rev, every)
             tot_cells = jax.lax.psum(n_cells, every)
@@ -1093,3 +1130,52 @@ def make_routed_serving_step(mesh: Mesh, flavor: str = "bandit", *,
           valid_docs, seed, healthy, asc, rcp)
 
     return step
+
+
+def make_sharded_stage1(mesh: Mesh, *, kprime: int = 8,
+                        max_candidates: int = 64,
+                        support: Tuple[float, float] = (0.0, 1.0)):
+    """Host-path stage-1 over a mesh-resident corpus: the same
+    ``CandidateSet`` as ``generate_candidates`` over the whole index.
+
+    Each shard scans its own token rows for every query token's top-k'
+    (``ann.token_topk``); the per-shard lists (B, T, k') are all-gathered
+    and merged in shard order, which is global position order, so ties
+    resolve exactly as one top-k over the whole index would. The index
+    itself never leaves its shard.
+
+      stage1(corpus_embs (C_pad, L, M), corpus_mask (C_pad, L),
+             queries (B, T, M)) -> (doc_ids (B, N), a (B, N, T), b (B, N, T))
+    """
+    mesh = auto_axes(mesh)
+    every = tuple(mesh.axis_names)
+
+    def local_hits(c_embs, c_mask, q):
+        c_loc, L, _ = c_embs.shape
+        kp = min(kprime, c_loc * L)
+        vals, docs = jax.vmap(
+            lambda qq: token_topk(c_embs, c_mask, qq, kp,
+                                  STAGE1_CHUNK_DOCS))(q)
+        docs = docs + _shard_index(every) * c_loc
+        return (jax.lax.all_gather(vals, every, axis=2, tiled=True),
+                jax.lax.all_gather(docs, every, axis=2, tiled=True))
+
+    def stage1(corpus_embs, corpus_mask, queries):
+        C = corpus_embs.shape[0]
+        vals, docs = jax.shard_map(
+            local_hits, mesh=mesh, check_vma=False,
+            in_specs=(P(every, None, None), P(every, None),
+                      P(None, None, None)),
+            out_specs=(P(None, None, None), P(None, None, None)),
+        )(corpus_embs, corpus_mask, queries)              # (B, T, S*k')
+        kp = min(kprime, C * corpus_embs.shape[1])
+        vals, pos = jax.lax.top_k(vals, kp)
+        docs = jnp.take_along_axis(docs, pos, axis=2)
+
+        def one(v, d):
+            cs = candidates_from_hits(v, d, C, max_candidates=max_candidates,
+                                      support=support)
+            return cs.doc_ids, cs.a, cs.b
+        return jax.vmap(one)(vals, docs)
+
+    return stage1

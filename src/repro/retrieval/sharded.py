@@ -34,6 +34,7 @@ from jax.sharding import Mesh, NamedSharding
 
 from repro.dist.sharding import corpus_axes, corpus_specs
 from repro.kernels.quant import CORPUS_FORMATS, QuantTokens, quantize
+from repro.launch.mesh import auto_axes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -127,19 +128,24 @@ def shard_corpus(embs, mask, mesh: Mesh, *, pooled=None, router=None,
     if corpus_format not in CORPUS_FORMATS:
         raise ValueError(f"unknown corpus format {corpus_format!r}; "
                          f"expected one of {CORPUS_FORMATS}")
-    embs = np.asarray(embs)
+    # A device-resident corpus (a jax.Array, possibly already laid out on
+    # the mesh) is padded and placed on device; a host corpus goes from
+    # host memory straight to its shards.
+    xp = jnp if isinstance(embs, jax.Array) else np
+    embs = xp.asarray(embs)
     if embs.dtype != jnp.bfloat16:
         embs = embs.astype(np.float32)
-    mask = np.asarray(mask, bool)
+    mask = xp.asarray(mask, bool)
     if embs.ndim != 3 or mask.ndim != 2 or embs.shape[:2] != mask.shape:
         raise ValueError("corpus must be (C, L, M) embs + (C, L) mask")
     C = embs.shape[0]
+    mesh = auto_axes(mesh)
     n_shards = int(np.prod([mesh.shape[a] for a in corpus_axes(mesh)]))
     c_loc = -(-max(C, 1) // n_shards)            # ceil; >=1 so shapes stay real
     pad = n_shards * c_loc - C
     if pad:
-        embs = np.pad(embs, ((0, pad), (0, 0), (0, 0)))
-        mask = np.pad(mask, ((0, pad), (0, 0)))  # pads False => masked out
+        embs = xp.pad(embs, ((0, pad), (0, 0), (0, 0)))
+        mask = xp.pad(mask, ((0, pad), (0, 0)))  # pads False => masked out
     valid = np.clip(C - c_loc * np.arange(n_shards), 0, c_loc).astype(np.int32)
     specs = corpus_specs(mesh)
     put = lambda x, s: jax.device_put(x, NamedSharding(mesh, s))

@@ -50,12 +50,14 @@ from repro.kernels import tuning
 from repro.kernels.ops import autotune_op
 from repro.kernels.quant import (CORPUS_FORMATS, corpus_nbytes,
                                  format_ordinal)
+from repro.launch.mesh import make_mesh
 from repro.retrieval.ann import generate_candidates
 from repro.retrieval.corpus import Corpus, build_corpus
 from repro.retrieval.service import (init_stream_state,
                                      make_routed_serving_step,
                                      make_serving_step,
                                      make_sharded_serving_step,
+                                     make_sharded_stage1,
                                      make_streaming_step)
 from repro.retrieval.sharded import route_batch
 from repro.serve.bucketing import (ShapeBuckets, pad_candidates, pad_queries,
@@ -503,7 +505,7 @@ class RetrievalEngine:
         if self.cfg.mesh_axes:
             names = tuple(a for a, _ in self.cfg.mesh_axes)
             shape = tuple(int(n) for _, n in self.cfg.mesh_axes)
-            mesh = jax.make_mesh(shape, names)
+            mesh = make_mesh(shape, names)
         elif self.cfg.stage1 == "local":
             raise ValueError("stage1='local' runs inside the corpus "
                              "shard_map and needs mesh_axes")
@@ -778,14 +780,18 @@ class RetrievalEngine:
                     "stage-1 ANN needs a dense corpus; quantized engines "
                     "serve candidate-carrying requests only")
             nb, kp, support = self._stage1_n, cfg.stage1_kprime, cfg.support
-
-            def stage1(ce, cm, q):
-                def one(qq):
-                    cs = generate_candidates(ce, cm, qq, kprime=kp,
+            if self.sharded is not None:
+                stage1 = make_sharded_stage1(self.sharded.mesh, kprime=kp,
                                              max_candidates=nb,
                                              support=support)
-                    return cs.doc_ids, cs.a, cs.b
-                return jax.vmap(one)(q)
+            else:
+                def stage1(ce, cm, q):
+                    def one(qq):
+                        cs = generate_candidates(ce, cm, qq, kprime=kp,
+                                                 max_candidates=nb,
+                                                 support=support)
+                        return cs.doc_ids, cs.a, cs.b
+                    return jax.vmap(one)(q)
 
             args = (self.corpus_embs, self.corpus_mask,
                     SDS((B, tb, M), jnp.float32))
@@ -955,9 +961,9 @@ class RetrievalEngine:
         plus two scalar-per-query psums across shards —
         ``scorecard_budget_bytes(B, S, max_k)``; candidate embeddings and
         reveal traffic must stay shard-local. Host stage-1 over a sharded
-        corpus legitimately all-gathers the index (the documented
-        exemption: candidate-less traffic belongs on the routed path), so
-        that one key is unbudgeted. Everything off-mesh gets budget 0.
+        corpus all-gathers every shard's per-token top-k' hit lists, a
+        different contract from the scorecard merge, so that one key is
+        unbudgeted. Everything off-mesh gets budget 0.
 
         Boundary residency: a bf16 corpus arms the promotion rule; a
         quantized corpus (``corpus_embs`` is a QuantTokens whose payload

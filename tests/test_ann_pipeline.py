@@ -37,6 +37,31 @@ def test_ann_bounds_are_valid_upper_bounds(ds, index):
         assert viol.max() <= 1e-5, f"bound violated by {viol.max()}"
 
 
+@pytest.mark.parametrize("chunk_docs", [1, 5, 32, 100])
+def test_chunked_stage1_scan_matches_whole_index(ds, index, chunk_docs):
+    """The document-chunked running top-k' scan returns the same
+    CandidateSet as one top_k over the whole (T, C*L) similarity matrix
+    (chunk_docs >= C), ragged last chunk included: identical ids and masks,
+    and values equal to f32 rounding (the CPU dot's summation order
+    depends on the operand's width)."""
+    C = index.doc_embs.shape[0]
+    for qi in range(ds.n_queries):
+        q = jnp.asarray(ds.queries[qi])
+        for quota in (None, jnp.int32(20)):
+            whole = generate_candidates(index.doc_embs, index.doc_mask, q,
+                                        quota, kprime=10, max_candidates=64,
+                                        chunk_docs=C)
+            got = generate_candidates(index.doc_embs, index.doc_mask, q,
+                                      quota, kprime=10, max_candidates=64,
+                                      chunk_docs=chunk_docs)
+            for a, b in zip(got, whole):
+                a, b = np.asarray(a), np.asarray(b)
+                if a.dtype == np.float32:
+                    np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+                else:
+                    np.testing.assert_array_equal(a, b)
+
+
 def test_ann_known_cells_match_truth(ds, index):
     q = jnp.asarray(ds.queries[0])
     cand = generate_candidates(index.doc_embs, index.doc_mask, q,

@@ -62,9 +62,10 @@ def test_maxsim_default_block_t_capped_not_full_axis(monkeypatch):
     mask = jnp.asarray(rng.random((N, L)) > 0.2)
     Q = jnp.asarray(rng.standard_normal((T, M)), jnp.float32)
     h = maxsim_op(E, mask, Q, block_l=32)          # default block_t
+    # Relative: f32 accumulation order differs on 128-wide dots (|h| ~ 30).
     np.testing.assert_allclose(np.asarray(h),
                                np.asarray(ref.maxsim_ref(E, mask, Q)),
-                               atol=1e-5)
+                               rtol=1e-6)
 
 
 def test_explicit_block_argument_beats_tuned_entry(monkeypatch):
