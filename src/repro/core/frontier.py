@@ -440,45 +440,48 @@ def run_pooled_bandit(
                        else _with_stats(compute_cells))
         flat_mask = doc_mask.reshape(Q * N)
 
-        new0 = flat_mask[:, None]                               # (Q*N, 1)
-        if carry is not None:
-            new0 = new0 & fresh_rows[:, None]
-        if pr_flat is not None:
-            # An init cell that stage 1 already revealed is not new: it must
-            # enter the stats exactly once (mirrors _apply_block_reveal's
-            # ``already`` skip in the chain body).
-            already0 = jnp.take_along_axis(pr_flat, flat_t0, axis=1)
-            new0 = new0 & ~already0
-        vals0, stats0 = cells_fused(all_docs,
-                                    flat_t0 + (all_docs // N * T)[:, None],
-                                    new0)
-        # Finite-score guard: sanitize the revealed values and, for rows
-        # where a non-finite value slipped into the in-kernel statistic
-        # accumulation, rebuild that row's deltas from the sanitized
-        # values. Rows with only finite cells keep the kernel's own stats
-        # bit for bit (no re-summation => chain/fused parity untouched).
-        bad0 = new0 & ~jnp.isfinite(vals0)
-        vals0 = sanitize(vals0)
-        vm0 = jnp.where(new0, vals0, 0.0)
-        fix0 = jnp.stack([jnp.sum(new0.astype(jnp.float32), -1),
-                          jnp.sum(vm0, -1), jnp.sum(vm0 * vm0, -1)], axis=-1)
-        stats0 = jnp.where(jnp.any(bad0, -1)[:, None], fix0, stats0)
-        cellvals0 = jnp.where(flat_mask[:, None],
-                              jnp.full((Q * N, T), _UNREV), 0.0)
-        if pr_flat is not None:
-            cellvals0 = jnp.where(pr_flat, pv_flat, cellvals0)
-            stats0 = stats0 + jnp.stack(
-                [jnp.sum(pr_flat, -1).astype(jnp.float32),
-                 jnp.sum(pv_flat, -1), jnp.sum(pv_flat * pv_flat, -1)],
-                axis=-1)
-        cellvals0 = cellvals0.at[all_docs[:, None], flat_t0].min(
-            jnp.where(new0, vals0, _UNREV))
-        if carry is not None:
-            cellvals0 = jnp.where(fresh_rows[:, None], cellvals0,
-                                  carry.cellvals)
-            stats0 = jnp.where(fresh_rows[:, None], stats0, carry.stats)
-        state = _FusedState(cellvals=cellvals0, stats=stats0,
-                            key=state_keys, rounds=rounds0, done=done0)
+        with jax.named_scope("frontier_init"):
+            new0 = flat_mask[:, None]                           # (Q*N, 1)
+            if carry is not None:
+                new0 = new0 & fresh_rows[:, None]
+            if pr_flat is not None:
+                # An init cell that stage 1 already revealed is not new: it
+                # must enter the stats exactly once (mirrors
+                # _apply_block_reveal's ``already`` skip in the chain body).
+                already0 = jnp.take_along_axis(pr_flat, flat_t0, axis=1)
+                new0 = new0 & ~already0
+            vals0, stats0 = cells_fused(all_docs,
+                                        flat_t0 + (all_docs // N * T)[:, None],
+                                        new0)
+            # Finite-score guard: sanitize the revealed values and, for
+            # rows where a non-finite value slipped into the in-kernel
+            # statistic accumulation, rebuild that row's deltas from the
+            # sanitized values. Rows with only finite cells keep the
+            # kernel's own stats bit for bit (no re-summation =>
+            # chain/fused parity untouched).
+            bad0 = new0 & ~jnp.isfinite(vals0)
+            vals0 = sanitize(vals0)
+            vm0 = jnp.where(new0, vals0, 0.0)
+            fix0 = jnp.stack([jnp.sum(new0.astype(jnp.float32), -1),
+                              jnp.sum(vm0, -1), jnp.sum(vm0 * vm0, -1)],
+                             axis=-1)
+            stats0 = jnp.where(jnp.any(bad0, -1)[:, None], fix0, stats0)
+            cellvals0 = jnp.where(flat_mask[:, None],
+                                  jnp.full((Q * N, T), _UNREV), 0.0)
+            if pr_flat is not None:
+                cellvals0 = jnp.where(pr_flat, pv_flat, cellvals0)
+                stats0 = stats0 + jnp.stack(
+                    [jnp.sum(pr_flat, -1).astype(jnp.float32),
+                     jnp.sum(pv_flat, -1), jnp.sum(pv_flat * pv_flat, -1)],
+                    axis=-1)
+            cellvals0 = cellvals0.at[all_docs[:, None], flat_t0].min(
+                jnp.where(new0, vals0, _UNREV))
+            if carry is not None:
+                cellvals0 = jnp.where(fresh_rows[:, None], cellvals0,
+                                      carry.cellvals)
+                stats0 = jnp.where(fresh_rows[:, None], stats0, carry.stats)
+            state = _FusedState(cellvals=cellvals0, stats=stats0,
+                                key=state_keys, rounds=rounds0, done=done0)
 
         def body(carry):
             st, trips, occ_sum = carry
@@ -524,8 +527,9 @@ def run_pooled_bandit(
                 done=st.done | (active & (sel.stop | no_progress)))
             return nxt, trips + 1, occ_sum + occ
 
-        state, trips, occ_sum = jax.lax.while_loop(
-            cond, body, (state, *zero_trip))
+        with jax.named_scope("frontier_round"):
+            state, trips, occ_sum = jax.lax.while_loop(
+                cond, body, (state, *zero_trip))
         res = finalize(state.stats[:, 0], state.stats[:, 1],
                        state.stats[:, 2], state.cellvals < _REV_THRESH,
                        state.rounds, trips, occ_sum,
@@ -577,13 +581,14 @@ def run_pooled_bandit(
             total=state.total + jnp.sum(pv_flat, -1),
             total_sq=state.total_sq + jnp.sum(pv_flat * pv_flat, -1))
 
-    init_vals = sanitize(compute_cells(all_docs,
-                                       flat_t0 + (all_docs // N * T)[:, None]))
-    init_valid = doc_mask.reshape(Q * N, 1)
-    if carry is not None:
-        init_valid = init_valid & fresh_rows[:, None]
-    state = _apply_block_reveal(state, all_docs, flat_t0, init_vals,
-                                init_valid)
+    with jax.named_scope("frontier_init"):
+        init_vals = sanitize(compute_cells(
+            all_docs, flat_t0 + (all_docs // N * T)[:, None]))
+        init_valid = doc_mask.reshape(Q * N, 1)
+        if carry is not None:
+            init_valid = init_valid & fresh_rows[:, None]
+        state = _apply_block_reveal(state, all_docs, flat_t0, init_vals,
+                                    init_valid)
 
     def per_query_intervals(st: BanditState) -> B.Intervals:
         return jax.vmap(get_intervals_q)(
@@ -615,8 +620,9 @@ def run_pooled_bandit(
         )
         return nxt, trips + 1, occ_sum + occ
 
-    state, trips, occ_sum = jax.lax.while_loop(
-        cond, body, (state, *zero_trip))
+    with jax.named_scope("frontier_round"):
+        state, trips, occ_sum = jax.lax.while_loop(
+            cond, body, (state, *zero_trip))
     res = finalize(state.n, state.total, state.total_sq, state.revealed,
                    state.rounds, trips, occ_sum,
                    jnp.any(state.revealed & (state.values <= _QUAR_THRESH),

@@ -152,5 +152,6 @@ def fused_reveal(doc_embs: jax.Array, doc_tok_mask: jax.Array,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="fused_reveal",
     )(doc_idx, *operands)
     return vals.reshape(F, G), stats.reshape(F, STATS_W)

@@ -67,21 +67,22 @@ def token_topk(index_embs, index_mask, query, kprime: int, chunk_docs: int):
         docs = jnp.concatenate([best[1], new[1]], 1)
         return vals, jnp.take_along_axis(docs, pos, axis=1)
 
-    chunk = min(max(chunk_docs, -(-kprime // L)), C)
-    n_full, rem = divmod(C, chunk)
-    best = chunk_topk(index_embs[:chunk], index_mask[:chunk], 0)
-    if n_full > 1:
-        def body(best, start):
-            e = jax.lax.dynamic_slice_in_dim(index_embs, start, chunk)
-            m = jax.lax.dynamic_slice_in_dim(index_mask, start, chunk)
-            return merge(best, chunk_topk(e, m, start)), None
-        best, _ = jax.lax.scan(
-            body, best, jnp.arange(1, n_full, dtype=jnp.int32) * chunk)
-    if rem:
-        tail = n_full * chunk
-        best = merge(best, chunk_topk(index_embs[tail:], index_mask[tail:],
-                                      tail))
-    return best
+    with jax.named_scope("stage1_scan"):
+        chunk = min(max(chunk_docs, -(-kprime // L)), C)
+        n_full, rem = divmod(C, chunk)
+        best = chunk_topk(index_embs[:chunk], index_mask[:chunk], 0)
+        if n_full > 1:
+            def body(best, start):
+                e = jax.lax.dynamic_slice_in_dim(index_embs, start, chunk)
+                m = jax.lax.dynamic_slice_in_dim(index_mask, start, chunk)
+                return merge(best, chunk_topk(e, m, start)), None
+            best, _ = jax.lax.scan(
+                body, best, jnp.arange(1, n_full, dtype=jnp.int32) * chunk)
+        if rem:
+            tail = n_full * chunk
+            best = merge(best, chunk_topk(index_embs[tail:], index_mask[tail:],
+                                          tail))
+        return best
 
 
 @functools.partial(jax.jit, static_argnames=("kprime", "max_candidates",
@@ -111,61 +112,63 @@ def candidates_from_hits(top_vals, hit_docs, n_docs: int, quota=None, *,
                          support: Tuple[float, float]) -> CandidateSet:
     """Eq. 15 candidate set from per-token top-k' hits (values and owning
     doc ids, (T, k') each, best first) over an ``n_docs``-document index."""
-    C = n_docs
-    T, kprime = top_vals.shape
-    s_kprime = top_vals[:, kprime - 1]
+    with jax.named_scope("stage1_candidates"):
+        C = n_docs
+        T, kprime = top_vals.shape
+        s_kprime = top_vals[:, kprime - 1]
 
-    # Candidate set = union of hit docs. If the union exceeds
-    # max_candidates, keep the docs with the HIGHEST best-hit similarity
-    # (arbitrary-id truncation would silently drop strong candidates).
-    doc_best = jnp.full((C,), _NEG).at[hit_docs.reshape(-1)].max(
-        top_vals.reshape(-1))
-    best_vals, best_ids = jax.lax.top_k(doc_best, min(max_candidates, C))
-    if C < max_candidates:               # pad to the static candidate count
-        pad = max_candidates - C
-        best_vals = jnp.pad(best_vals, (0, pad), constant_values=_NEG)
-        best_ids = jnp.pad(best_ids, (0, pad), constant_values=0)
-    sel = best_vals > _NEG / 2
-    if quota is not None:
-        # Skew-aware routing cap: best_vals is descending, so rank ==
-        # position; keep only the strongest ``quota`` candidates.
-        sel = sel & (jnp.arange(max_candidates) < quota)
-    sentinel = jnp.iinfo(jnp.int32).max
-    sorted_slots = jnp.sort(jnp.where(sel, best_ids, sentinel))
-    # Keep the sentinel-padded array around: it stays ascending, which the
-    # searchsorted hit-lookup below requires (-1 padding would break the
-    # sort order and silently drop exact b-values for high doc ids).
-    cands = jnp.where(sorted_slots == sentinel, -1,
-                      sorted_slots).astype(jnp.int32)
-    doc_mask = cands >= 0
+        # Candidate set = union of hit docs. If the union exceeds
+        # max_candidates, keep the docs with the HIGHEST best-hit similarity
+        # (arbitrary-id truncation would silently drop strong candidates).
+        doc_best = jnp.full((C,), _NEG).at[hit_docs.reshape(-1)].max(
+            top_vals.reshape(-1))
+        best_vals, best_ids = jax.lax.top_k(doc_best, min(max_candidates, C))
+        if C < max_candidates:           # pad to the static candidate count
+            pad = max_candidates - C
+            best_vals = jnp.pad(best_vals, (0, pad), constant_values=_NEG)
+            best_ids = jnp.pad(best_ids, (0, pad), constant_values=0)
+        sel = best_vals > _NEG / 2
+        if quota is not None:
+            # Skew-aware routing cap: best_vals is descending, so rank ==
+            # position; keep only the strongest ``quota`` candidates.
+            sel = sel & (jnp.arange(max_candidates) < quota)
+        sentinel = jnp.iinfo(jnp.int32).max
+        sorted_slots = jnp.sort(jnp.where(sel, best_ids, sentinel))
+        # Keep the sentinel-padded array around: it stays ascending, which the
+        # searchsorted hit-lookup below requires (-1 padding would break the
+        # sort order and silently drop exact b-values for high doc ids).
+        cands = jnp.where(sorted_slots == sentinel, -1,
+                          sorted_slots).astype(jnp.int32)
+        doc_mask = cands >= 0
 
-    a_lo, b_hi = support
-    a = jnp.full((max_candidates, T), jnp.float32(a_lo))
-    # Default upper bound: the k'-th neighbor similarity per token (Eq. 15).
-    b = jnp.broadcast_to(jnp.maximum(s_kprime, a_lo)[None, :],
-                         (max_candidates, T)).astype(jnp.float32)
+        a_lo, b_hi = support
+        a = jnp.full((max_candidates, T), jnp.float32(a_lo))
+        # Default upper bound: the k'-th neighbor similarity per token
+        # (Eq. 15).
+        b = jnp.broadcast_to(jnp.maximum(s_kprime, a_lo)[None, :],
+                             (max_candidates, T)).astype(jnp.float32)
 
-    # Hit cells: exact h value via scatter-max into candidate rows.
-    pos = jnp.searchsorted(sorted_slots, hit_docs)                 # (T, k')
-    pos = jnp.clip(pos, 0, max_candidates - 1)
-    is_cand = jnp.take(sorted_slots, pos) == hit_docs
-    t_grid = jnp.broadcast_to(jnp.arange(T)[:, None], hit_docs.shape)
-    safe_pos = jnp.where(is_cand, pos, max_candidates - 1)
+        # Hit cells: exact h value via scatter-max into candidate rows.
+        pos = jnp.searchsorted(sorted_slots, hit_docs)             # (T, k')
+        pos = jnp.clip(pos, 0, max_candidates - 1)
+        is_cand = jnp.take(sorted_slots, pos) == hit_docs
+        t_grid = jnp.broadcast_to(jnp.arange(T)[:, None], hit_docs.shape)
+        safe_pos = jnp.where(is_cand, pos, max_candidates - 1)
 
-    known_vals = jnp.full((max_candidates, T), _NEG)
-    known_vals = known_vals.at[safe_pos, t_grid].max(
-        jnp.where(is_cand, top_vals, _NEG))
-    known_mask = known_vals > _NEG / 2
-    known_vals = jnp.where(known_mask, known_vals, 0.0)
+        known_vals = jnp.full((max_candidates, T), _NEG)
+        known_vals = known_vals.at[safe_pos, t_grid].max(
+            jnp.where(is_cand, top_vals, _NEG))
+        known_mask = known_vals > _NEG / 2
+        known_vals = jnp.where(known_mask, known_vals, 0.0)
 
-    b = jnp.where(known_mask, known_vals, b)
-    b = jnp.clip(b, a_lo, b_hi)
-    a = jnp.where(doc_mask[:, None], a, 0.0)
-    b = jnp.where(doc_mask[:, None], b, 0.0)
+        b = jnp.where(known_mask, known_vals, b)
+        b = jnp.clip(b, a_lo, b_hi)
+        a = jnp.where(doc_mask[:, None], a, 0.0)
+        b = jnp.where(doc_mask[:, None], b, 0.0)
 
-    return CandidateSet(doc_ids=cands, doc_mask=doc_mask, a=a, b=b,
-                        known_mask=known_mask & doc_mask[:, None],
-                        known_vals=known_vals, s_kprime=s_kprime)
+        return CandidateSet(doc_ids=cands, doc_mask=doc_mask, a=a, b=b,
+                            known_mask=known_mask & doc_mask[:, None],
+                            known_vals=known_vals, s_kprime=s_kprime)
 
 
 def generic_bounds(n: int, t: int,

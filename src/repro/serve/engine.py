@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import gc
 import itertools
 import os
 import queue as queue_mod
@@ -40,6 +41,7 @@ from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.analysis.hlo_audit import (AuditSpec, audit_executable,
                                       scorecard_budget_bytes)
@@ -261,6 +263,9 @@ class Completion:
     # work queued and flushing impossible, supervision budget exhausted,
     # continuous-mode slot lost to a thread restart). topk_ids are all -1.
     error: Optional[str] = None
+    # Ordinal of the batch that served the request (its BatchRecord.bid and
+    # the ``bid`` of its batch's profiler spans); -1 on an error completion.
+    bid: int = -1
 
 
 @dataclasses.dataclass
@@ -269,7 +274,6 @@ class BatchRecord:
     flavor: str
     n_real: int
     occupancy: float              # n_real / batch_size
-    service_s: float              # release -> results materialized
     reveal_fraction: float
     # Reveal-engine diagnostics (service.py stats vector): live-slot
     # fraction of the pooled frontier (or lockstep duty cycle for the
@@ -294,6 +298,26 @@ class BatchRecord:
     quarantined: float = 0.0
     # Fidelity-ladder rung the batch ran at (0 = full fidelity).
     degrade_level: int = 0
+    # Batch ordinal (the ``bid`` of its completions and profiler spans) and
+    # its stage stamps on the engine clock: released by the batcher,
+    # prepared (bucketed, padded, stage-1 done), launched on the device,
+    # results ready, copied to the host, completions delivered. A stage a
+    # path lacks carries its neighbour's stamp (the continuous stream
+    # prepares before its release stamp and copies after ``t_done``).
+    # ``stage1_s`` is the time spent in stage-1 (0.0 without it).
+    bid: int = -1
+    t_release: float = 0.0
+    t_prepared: float = 0.0
+    stage1_s: float = 0.0
+    t_dispatched: float = 0.0
+    t_ready: float = 0.0
+    t_done: float = 0.0
+    t_delivered: float = 0.0
+
+    @property
+    def service_s(self) -> float:
+        """Release -> results materialized (feeds the service-time EMA)."""
+        return self.t_done - self.t_release
 
 
 class EngineMetrics:
@@ -307,6 +331,8 @@ class EngineMetrics:
         self._lock = threading.Lock()
         self.completions: List[Completion] = []
         self.batches: List[BatchRecord] = []
+        # Recorded batches whose completions are not yet delivered, by bid.
+        self._undelivered: Dict[int, BatchRecord] = {}
         self.compiles: Dict[tuple, int] = {}
         self.compiles_after_warmup: int = 0
         # Backpressure accounting (async engine): requests refused outright
@@ -337,6 +363,14 @@ class EngineMetrics:
         with self._lock:
             self.batches.append(record)
             self.completions.extend(completions)
+            self._undelivered[record.bid] = record
+
+    def record_delivered(self, bid: int, t: float) -> None:
+        """Stamp ``t_delivered`` on the recorded batch ``bid``."""
+        with self._lock:
+            record = self._undelivered.pop(bid, None)
+            if record is not None:
+                record.t_delivered = t
 
     def record_rejected(self) -> None:
         with self._lock:
@@ -460,6 +494,10 @@ class _Prepared(NamedTuple):
     # prepare time (None = fully healthy, i.e. all 1.0).
     coverage: Optional[np.ndarray] = None
     degrade_level: int = 0
+    # Stage stamps carried to the batch's BatchRecord (engine clock).
+    t_prepared: float = 0.0
+    stage1_s: float = 0.0
+    t_dispatched: float = 0.0
 
 
 class RetrievalEngine:
@@ -650,7 +688,8 @@ class RetrievalEngine:
             exe = self._exec.get(key)
             if exe is not None:
                 return exe
-            exe = self._build(key)
+            with TraceAnnotation("engine.compile"):
+                exe = self._build(key)
             self._exec[key] = exe
         self.metrics.record_compile(key, after_warmup=self._warmed)
         return exe
@@ -1106,13 +1145,23 @@ class RetrievalEngine:
                      n_real: int) -> List[Completion]:
         """Synchronous path: prepare, dispatch, and harvest back to back."""
         prep = self._prepare_batch(reqs, n_real, self.clock())
-        return self._finish_batch(prep, self._dispatch_batch(prep))
+        prep, out = self._launch(prep)
+        with TraceAnnotation("engine.harvest", bid=prep.bid):
+            comps = self._finish_batch(prep, out)
+        self.metrics.record_delivered(prep.bid, self.clock())
+        return comps
 
     def _dispatch_batch(self, prep: _Prepared):
         """Launch the batch's executable. JAX dispatch is asynchronous:
         this returns device arrays immediately; only ``_finish_batch``
         blocks on them — the property the async pipeline overlaps on."""
         return prep.exe(*prep.args)
+
+    def _launch(self, prep: _Prepared) -> Tuple[_Prepared, Any]:
+        """``_dispatch_batch`` under its span; stamps ``t_dispatched``."""
+        with TraceAnnotation("engine.dispatch", bid=prep.bid):
+            out = self._dispatch_batch(prep)
+        return prep._replace(t_dispatched=self.clock()), out
 
     def _degrade_level(self, real: Sequence[Request], flavor: str) -> int:
         """Fidelity-ladder rung for this batch: 0 unless the degrade
@@ -1134,15 +1183,29 @@ class RetrievalEngine:
     def _prepare_batch(self, reqs: Sequence[Request], n_real: int,
                        t_release: float) -> _Prepared:
         """Host-side batch assembly: bucket, pad, stage-1, route — no
-        waiting on the main step executable."""
+        waiting on the main step executable. Assigns the batch its ``bid``
+        and stamps ``t_prepared``."""
+        bid = next(self._bid)
+        with TraceAnnotation("engine.prepare", bid=bid):
+            real = list(reqs[:n_real])
+            tb = self.buckets.token_bucket(max(r.query.shape[0]
+                                               for r in real))
+            if self._routed and all(r.cand_ids is None for r in reqs):
+                prep = self._prepare_batch_routed(reqs, real, n_real, tb,
+                                                  t_release, bid)
+            else:
+                prep = self._prepare_batch_local(reqs, real, n_real, tb,
+                                                 t_release, bid)
+        return prep._replace(t_prepared=self.clock())
+
+    def _prepare_batch_local(self, reqs: Sequence[Request],
+                             real: List[Request], n_real: int, tb: int,
+                             t_release: float, bid: int) -> _Prepared:
+        """Batches served by the step executable: candidate lists padded
+        into their bucket, stage-1 run first for requests without one."""
         cfg = self.cfg
-        real = list(reqs[:n_real])
-        tb = self.buckets.token_bucket(max(r.query.shape[0] for r in real))
         provided = [r.cand_ids for r in reqs]
         missing = [c is None for c in provided]
-        if self._routed and all(missing):
-            return self._prepare_batch_routed(reqs, real, n_real, tb,
-                                              t_release)
         n_need = max([len(c) for c in provided if c is not None], default=0)
         if any(missing):
             n_need = max(n_need, self._stage1_n)
@@ -1153,10 +1216,15 @@ class RetrievalEngine:
         n_toks = [r.query.shape[0] for r in reqs]
         a, b = support_bounds(cand, n_toks, tb, cfg.support)
 
+        stage1_s = 0.0
         if any(missing):
-            ids1, a1, b1 = self._executable(("stage1", tb))(
-                self.corpus_embs, self.corpus_mask, jnp.asarray(queries))
-            ids1, a1, b1 = (np.asarray(ids1), np.asarray(a1), np.asarray(b1))
+            t0 = self.clock()
+            with TraceAnnotation("engine.stage1", bid=bid):
+                ids1, a1, b1 = self._executable(("stage1", tb))(
+                    self.corpus_embs, self.corpus_mask, jnp.asarray(queries))
+                ids1, a1, b1 = (np.asarray(ids1), np.asarray(a1),
+                                np.asarray(b1))
+            stage1_s = self.clock() - t0
             for i, miss in enumerate(missing):
                 if miss:
                     cand[i, :self._stage1_n] = ids1[i]
@@ -1197,7 +1265,7 @@ class RetrievalEngine:
                     jnp.asarray(cand), jnp.asarray(a), jnp.asarray(b),
                     seed) + knob_args
         return _Prepared(real, n_real, (tb, nb), flavor, exe, args,
-                         t_release, next(self._bid), cov, level)
+                         t_release, bid, cov, level, stage1_s=stage1_s)
 
     @staticmethod
     def _candidate_coverage(cand: np.ndarray, real: Sequence[Request],
@@ -1217,7 +1285,7 @@ class RetrievalEngine:
 
     def _prepare_batch_routed(self, reqs: Sequence[Request],
                               real: List[Request], n_real: int, tb: int,
-                              t_release: float) -> _Prepared:
+                              t_release: float, bid: int) -> _Prepared:
         """One-shard_map dispatch for candidate-less batches on a routed
         engine: no host stage-1, no routing tables — queries in,
         scorecards out."""
@@ -1242,16 +1310,31 @@ class RetrievalEngine:
                 jnp.asarray(queries), self._valid_docs, seed,
                 jnp.asarray(hl), jnp.float32(a_s), jnp.int32(r_c))
         return _Prepared(real, n_real, (tb, nb), flavor, exe, args,
-                         t_release, next(self._bid), cov, level)
+                         t_release, bid, cov, level)
 
     def _finish_batch(self, prep: _Prepared, out) -> List[Completion]:
         """Completion harvest: the ONLY stage that blocks on the device."""
+        with TraceAnnotation("engine.harvest.wait", bid=prep.bid):
+            out = jax.block_until_ready(out)
+        t_ready = self.clock()
+        with TraceAnnotation("engine.harvest.copy", bid=prep.bid):
+            return self._completions(prep, out, t_ready)
+
+    def _observe_service(self, service_s: float) -> None:
+        """Fold one batch's service time into the admission EMA."""
+        with self._state_lock:
+            self._service_ema = (service_s if not self.metrics.batches
+                                 else 0.7 * self._service_ema
+                                 + 0.3 * service_s)
+
+    def _completions(self, prep: _Prepared, out,
+                     t_ready: float) -> List[Completion]:
+        """Copy a finished batch to the host; record it and build its
+        completions."""
         cfg = self.cfg
         real, n_real = prep.real, prep.n_real
         bucket, flavor, t_release = prep.bucket, prep.flavor, prep.t_release
-        scores, gids, frac, stats = jax.block_until_ready(out)
-        scores, gids, frac, stats = (np.asarray(scores), np.asarray(gids),
-                                     np.asarray(frac), np.asarray(stats))
+        scores, gids, frac, stats = (np.asarray(x) for x in out)
         t_done = self.clock()
 
         shard_quota = None
@@ -1271,15 +1354,10 @@ class RetrievalEngine:
             agg = (float(stats[0]), float(stats[1]), float(stats[2]))
             quarantined = float(stats[3])
 
-        service_s = t_done - t_release
-        with self._state_lock:
-            self._service_ema = (service_s if not self.metrics.batches
-                                 else 0.7 * self._service_ema
-                                 + 0.3 * service_s)
+        self._observe_service(t_done - t_release)
         record = BatchRecord(
             bucket=bucket, flavor=flavor, n_real=n_real,
             occupancy=n_real / cfg.batch_size,
-            service_s=service_s,
             reveal_fraction=float(np.mean(frac[:n_real])),
             frontier_occupancy=agg[0],
             total_rounds=agg[1],
@@ -1288,7 +1366,10 @@ class RetrievalEngine:
             shard_rounds=shard_rounds,
             shard_quota_share=shard_quota,
             quarantined=quarantined,
-            degrade_level=prep.degrade_level)
+            degrade_level=prep.degrade_level,
+            bid=prep.bid, t_release=t_release, t_prepared=prep.t_prepared,
+            stage1_s=prep.stage1_s, t_dispatched=prep.t_dispatched,
+            t_ready=t_ready, t_done=t_done)
 
         done: List[Completion] = []
         for i, r in enumerate(real):
@@ -1311,7 +1392,7 @@ class RetrievalEngine:
                 coverage=(float(prep.coverage[i])
                           if prep.coverage is not None else 1.0)
                          * r.coverage_scale,
-                degrade_level=prep.degrade_level)
+                degrade_level=prep.degrade_level, bid=prep.bid)
             done.append(comp)
         self.metrics.record_batch(record, done)
         return done
@@ -1346,7 +1427,9 @@ THREAD_ENTRY_POINTS = {
 #              value (the supervisor handle);
 #   ordered  — writes happen-before the reading thread starts (start()'s
 #              thread bookkeeping, supervisor-callback state mutated only
-#              while the watched thread is dead) or after it joins;
+#              while the watched thread is dead) or after it joins, or
+#              both fall in one garbage collection, which the interpreter
+#              runs on one thread at a time (the open gc span);
 #   init     — written once before any serving thread exists (warmup flag).
 GUARDED_BY = {
     "_futures": "_done_cv",
@@ -1364,6 +1447,7 @@ GUARDED_BY = {
     "_batcher": "internal",
     "_supervisor": "atomic",
     "_admit_holding": "ordered",
+    "_gc_trace": "ordered",
     "_harvested": "ordered",
     "_stream_slots": "ordered",
     "_targets": "ordered",
@@ -1459,6 +1543,8 @@ class AsyncRetrievalEngine(RetrievalEngine):
         self._harvested: set = set()
         self._delivered_rids: set = set()
         self._stream_slots: List[Optional[Request]] = []
+        # The open ``engine.gc`` span of the collection in progress.
+        self._gc_trace: Optional[TraceAnnotation] = None
 
     # -- lifecycle --------------------------------------------------------
 
@@ -1476,6 +1562,7 @@ class AsyncRetrievalEngine(RetrievalEngine):
                              "repro-dispatch": self._dispatch_loop}
         self._thread_by_name = {}
         self._started = True
+        gc.callbacks.append(self._gc_span)
         if self.cfg.supervise:
             self._supervisor = Supervisor(
                 max_restarts=self.cfg.max_thread_restarts,
@@ -1541,10 +1628,22 @@ class AsyncRetrievalEngine(RetrievalEngine):
         for t in list(self._thread_by_name.values()):
             t.join(timeout=60.0)
         self._started = False
+        gc.callbacks.remove(self._gc_span)
         if self._thread_exc is None:
             self._shutdown_flush()
         self._fail_pending("engine stopped before serving this request")
         self._raise_if_failed()
+
+    def _gc_span(self, phase: str, info: Dict[str, int]) -> None:
+        """``gc.callbacks`` hook while started: one ``engine.gc`` span per
+        collection, on whichever thread collects."""
+        if phase == "start":
+            self._gc_trace = TraceAnnotation("engine.gc",
+                                             generation=info["generation"])
+            self._gc_trace.__enter__()
+        elif self._gc_trace is not None:
+            self._gc_trace.__exit__(None, None, None)
+            self._gc_trace = None
 
     def __enter__(self) -> "AsyncRetrievalEngine":
         return self.start()
@@ -1682,6 +1781,13 @@ class AsyncRetrievalEngine(RetrievalEngine):
         with self._completed_lock:
             self._completed.extend(fresh)
 
+    def _deliver_batch(self, bid: int, comps: Sequence[Completion]) -> None:
+        """``_deliver`` one batch's completions; stamps its
+        ``t_delivered``."""
+        with TraceAnnotation("engine.deliver", bid=bid):
+            self._deliver(comps)
+        self.metrics.record_delivered(bid, self.clock())
+
     def poll(self) -> List[Completion]:
         """Un-started: serve synchronously (parity-oracle mode). Started:
         non-blocking pop of everything completed since the last poll.
@@ -1743,16 +1849,17 @@ class AsyncRetrievalEngine(RetrievalEngine):
                     prep = self._prepare_batch(out[0], out[1], self.clock())
             if prep is not None:
                 self._admit_holding = prep
-                while True:
-                    try:
-                        self._prep_q.put(prep, timeout=0.1)
-                        self._admit_holding = None
-                        break
-                    except queue_mod.Full:
-                        if self._stop_evt.is_set():
-                            # still holding: the stop flush serves it
-                            self._put_stop()
-                            return
+                with TraceAnnotation("engine.offer", bid=prep.bid):
+                    while True:
+                        try:
+                            self._prep_q.put(prep, timeout=0.1)
+                            self._admit_holding = None
+                            break
+                        except queue_mod.Full:
+                            if self._stop_evt.is_set():
+                                # still holding: the stop flush serves it
+                                self._put_stop()
+                                return
                 continue
             if self._stop_evt.is_set():
                 self._put_stop()
@@ -1763,7 +1870,8 @@ class AsyncRetrievalEngine(RetrievalEngine):
                 tmo = (self._poll_interval if exp is None
                        else min(max(exp - now, 0.0), self._poll_interval))
                 if tmo > 0:
-                    self._work_cv.wait(timeout=tmo)
+                    with TraceAnnotation("engine.admit.idle"):
+                        self._work_cv.wait(timeout=tmo)
 
     def _put_stop(self) -> None:
         """Best-effort dispatch sentinel: never block on a full queue (the
@@ -1790,9 +1898,10 @@ class AsyncRetrievalEngine(RetrievalEngine):
                 return False
             p, o = self._disp_inflight[0]
         if p.bid not in self._harvested:
-            comps = self._finish_batch(p, o)
-            self._harvested.add(p.bid)
-            self._deliver(comps)
+            with TraceAnnotation("engine.harvest", bid=p.bid):
+                comps = self._finish_batch(p, o)
+                self._harvested.add(p.bid)
+                self._deliver_batch(p.bid, comps)
         with self._inflight_lock:
             if self._disp_inflight and self._disp_inflight[0][0].bid == p.bid:
                 self._disp_inflight.popleft()
@@ -1808,18 +1917,18 @@ class AsyncRetrievalEngine(RetrievalEngine):
         depth = self.cfg.pipeline_depth
         while True:
             self._chaos("dispatch")
-            try:
-                prep = self._prep_q.get(timeout=self._poll_interval)
-            except queue_mod.Empty:
-                prep = None
+            with TraceAnnotation("engine.dispatch.idle"):
+                try:
+                    prep = self._prep_q.get(timeout=self._poll_interval)
+                except queue_mod.Empty:
+                    prep = None
             if prep is _STOP:
                 while self._harvest_head():
                     pass
                 return
             if prep is not None:
                 with self._inflight_lock:
-                    self._disp_inflight.append(
-                        (prep, self._dispatch_batch(prep)))
+                    self._disp_inflight.append(self._launch(prep))
                     self._inflight = len(self._disp_inflight)
                     full = len(self._disp_inflight) >= depth
                 if full:
@@ -1854,16 +1963,19 @@ class AsyncRetrievalEngine(RetrievalEngine):
         for prep in leftovers:
             if prep.bid in self._harvested:
                 continue
-            comps = self._finish_batch(prep, self._dispatch_batch(prep))
-            self._harvested.add(prep.bid)
-            self._deliver(comps)
+            prep, out = self._launch(prep)
+            with TraceAnnotation("engine.harvest", bid=prep.bid):
+                comps = self._finish_batch(prep, out)
+                self._harvested.add(prep.bid)
+                self._deliver_batch(prep.bid, comps)
         while True:
             out = self._batcher.poll() or self._batcher.flush()
             if out is None:
                 break
-            prep = self._prepare_batch(out[0], out[1], self.clock())
-            self._deliver(self._finish_batch(
-                prep, self._dispatch_batch(prep)))
+            prep, out = self._launch(
+                self._prepare_batch(out[0], out[1], self.clock()))
+            with TraceAnnotation("engine.harvest", bid=prep.bid):
+                self._deliver_batch(prep.bid, self._finish_batch(prep, out))
 
     def _error_completion(self, rid: int, reason: str,
                           k: Optional[int] = None) -> Completion:
@@ -1938,6 +2050,7 @@ class AsyncRetrievalEngine(RetrievalEngine):
                 slot_fill[s] = self.clock()
                 newly.append(s)
             fresh = np.zeros((B,), bool)
+            stage1_s = 0.0
             if newly:
                 need = [s for s in newly if slot[s].cand_ids is None]
                 if need:
@@ -1945,11 +2058,14 @@ class AsyncRetrievalEngine(RetrievalEngine):
                     for s in need:
                         q = slot[s].query
                         q_pad[s, :q.shape[0]] = q
-                    ids1, a1, b1 = self._executable(("stage1", tb))(
-                        self.corpus_embs, self.corpus_mask,
-                        jnp.asarray(q_pad))
-                    ids1, a1, b1 = (np.asarray(ids1), np.asarray(a1),
-                                    np.asarray(b1))
+                    t1 = self.clock()
+                    with TraceAnnotation("engine.stage1"):
+                        ids1, a1, b1 = self._executable(("stage1", tb))(
+                            self.corpus_embs, self.corpus_mask,
+                            jnp.asarray(q_pad))
+                        ids1, a1, b1 = (np.asarray(ids1), np.asarray(a1),
+                                        np.asarray(b1))
+                    stage1_s = self.clock() - t1
                 for s in newly:
                     r = slot[s]
                     queries[s] = 0.0
@@ -1981,13 +2097,17 @@ class AsyncRetrievalEngine(RetrievalEngine):
                 continue
 
             # 2. One slice: every live slot advances trip_limit rounds.
+            bid = next(self._bid)
             t0 = self.clock()
-            scores, gids, frac, stats, harvest, state = exe(
-                self.corpus_embs, self.corpus_mask, jnp.asarray(queries),
-                jnp.asarray(cand), jnp.asarray(a_np), jnp.asarray(b_np),
-                state, jnp.asarray(fresh), keys)
-            scores, gids, frac, stats, harvest = jax.block_until_ready(
-                (scores, gids, frac, stats, harvest))
+            with TraceAnnotation("engine.dispatch", bid=bid):
+                scores, gids, frac, stats, harvest, state = exe(
+                    self.corpus_embs, self.corpus_mask, jnp.asarray(queries),
+                    jnp.asarray(cand), jnp.asarray(a_np), jnp.asarray(b_np),
+                    state, jnp.asarray(fresh), keys)
+            t_dispatched = self.clock()
+            with TraceAnnotation("engine.harvest.wait", bid=bid):
+                scores, gids, frac, stats, harvest = jax.block_until_ready(
+                    (scores, gids, frac, stats, harvest))
             t_done = self.clock()
             scores, gids, frac, stats, harvest = (
                 np.asarray(scores), np.asarray(gids), np.asarray(frac),
@@ -2009,19 +2129,18 @@ class AsyncRetrievalEngine(RetrievalEngine):
                                    and t_done > r.deadline_abs + 1e-9),
                     flavor="bandit", bucket=(tb, nb),
                     reveal_fraction=float(frac[s]),
-                    coverage=r.coverage_scale))
+                    coverage=r.coverage_scale, bid=bid))
                 slot[s] = None
-            service_s = t_done - t0
-            with self._state_lock:
-                self._service_ema = (
-                    service_s if not self.metrics.batches
-                    else 0.7 * self._service_ema + 0.3 * service_s)
+            self._observe_service(t_done - t0)
             self.metrics.record_batch(BatchRecord(
                 bucket=(tb, nb), flavor="bandit", n_real=len(live),
-                occupancy=len(live) / B, service_s=service_s,
+                occupancy=len(live) / B,
                 reveal_fraction=float(np.mean(frac[live])),
                 frontier_occupancy=float(stats[0]),
                 total_rounds=float(stats[1]),
                 lockstep_waste=float(stats[2]),
-                quarantined=float(stats[3])), comps)
-            self._deliver(comps)
+                quarantined=float(stats[3]),
+                bid=bid, t_release=t0, t_prepared=t0, stage1_s=stage1_s,
+                t_dispatched=t_dispatched, t_ready=t_done,
+                t_done=t_done), comps)
+            self._deliver_batch(bid, comps)

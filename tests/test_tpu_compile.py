@@ -13,6 +13,7 @@ process at a time may load the TPU library, and every test worker imports
 this module.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -112,7 +113,13 @@ def test_bandit_rerank_step_compiles_for_v5e(one_chip, monkeypatch):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     C, L, B, T, N = 131072, 128, 8, 32, 256
-    _compile_for_chip(run, sds((C, L, M), jnp.bfloat16),
-                      sds((C, L), jnp.bool_), sds((B, T, M), jnp.float32),
-                      sds((B, N), jnp.int32), sds((B, N, T), jnp.float32),
-                      sds((B, N, T), jnp.float32), sds((), jnp.int32))
+    text = _compile_for_chip(
+        run, sds((C, L, M), jnp.bfloat16), sds((C, L), jnp.bool_),
+        sds((B, T, M), jnp.float32), sds((B, N), jnp.int32),
+        sds((B, N, T), jnp.float32), sds((B, N, T), jnp.float32),
+        sds((), jnp.int32)).as_text()
+    # The benchmark finds the kernel by its instruction prefix in the device
+    # trace; XProf groups the step's operations by the frontier's scopes.
+    assert re.search(r"%fused_reveal\.\d+ = [^\n]*custom-call", text)
+    for scope in ("frontier_init", "frontier_round"):
+        assert f"/{scope}/" in text, scope
