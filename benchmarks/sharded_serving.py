@@ -61,7 +61,7 @@ def _worker(n_shards: int, n_docs: int, B: int, N: int, T: int, L: int,
     import numpy as np
 
     from repro.launch.mesh import make_host_mesh
-    from repro.retrieval.ann import generate_candidates
+    from repro.retrieval.ann import generate_candidates_batch
     from repro.retrieval.service import (make_rerank_dense_step,
                                          make_routed_serving_step,
                                          make_sharded_serving_step)
@@ -133,9 +133,11 @@ def _worker(n_shards: int, n_docs: int, B: int, N: int, T: int, L: int,
         mesh, "bandit", topk=k, n_local=N, n_total=N, kprime=kprime,
         alpha_ef=alpha_ef, block_docs=8, block_tokens=4))
     cents, mass = sc.router.centroids, sc.router.shard_mass
-    gen = jax.jit(jax.vmap(lambda qq: generate_candidates(
-        jnp.asarray(emb), jnp.asarray(msk), qq, kprime=kprime,
-        max_candidates=N)))
+    emb_d, msk_d = jnp.asarray(emb), jnp.asarray(msk)
+
+    def gen(qq):
+        return generate_candidates_batch(emb_d, msk_d, qq, kprime=kprime,
+                                         max_candidates=N)
 
     def queries_uniform(i):
         r = np.random.default_rng(2000 + i)

@@ -25,8 +25,8 @@ import jax.numpy as jnp
 
 _NEG = jnp.float32(-3e38)
 # Docs per stage-1 scan step: the (T, chunk*L) f32 similarity block is
-# 32 MiB per query at T=32, L=128, so a vmapped batch of 8 stays near 1.3 GiB
-# of temp next to a resident index.
+# 32 MiB per query at T=32, L=128, so a batch of 8 needs about 256 MiB of
+# temp next to a resident index.
 STAGE1_CHUNK_DOCS = 2048
 
 
@@ -50,13 +50,20 @@ def token_topk(index_embs, index_mask, query, kprime: int, chunk_docs: int):
     (T, C*L) similarity matrix never exists, so a full-size index fits next
     to its scan. Ties keep the lower token position, exactly as one top_k
     over the whole index does (the running list is merged first).
-    Returns (values (T, k'), owning doc ids (T, k'))."""
+
+    ``query`` is (..., T, M): every query token of every leading index is
+    one row of a single (R, M) scan, and each row is selected on its own.
+    Callers pass a batch here rather than vmapping: a vmapped ``top_k`` has
+    a rank-3 operand, which the TPU compiler lowers to a full sort of each
+    chunk's C*L similarities; a rank-2 one lowers to its TopK.
+    Returns (values (..., T, k'), owning doc ids (..., T, k'))."""
     C, L, M = index_embs.shape
-    q = query.astype(jnp.float32)
+    lead = query.shape[:-1]
+    q = query.reshape(-1, M).astype(jnp.float32)                   # (R, M)
 
     def chunk_topk(embs, mask, start):
         n = embs.shape[0]
-        sims = q @ embs.reshape(n * L, M).astype(jnp.float32).T    # (T, n*L)
+        sims = q @ embs.reshape(n * L, M).astype(jnp.float32).T    # (R, n*L)
         sims = jnp.where(mask.reshape(-1)[None, :], sims, _NEG)
         vals, idx = jax.lax.top_k(sims, min(kprime, n * L))
         return vals, start + idx // L
@@ -82,11 +89,14 @@ def token_topk(index_embs, index_mask, query, kprime: int, chunk_docs: int):
             tail = n_full * chunk
             best = merge(best, chunk_topk(index_embs[tail:], index_mask[tail:],
                                           tail))
-        return best
+        vals, docs = best
+        return (vals.reshape(*lead, kprime), docs.reshape(*lead, kprime))
 
 
-@functools.partial(jax.jit, static_argnames=("kprime", "max_candidates",
-                                             "support", "chunk_docs"))
+_STAGE1_STATIC = ("kprime", "max_candidates", "support", "chunk_docs")
+
+
+@functools.partial(jax.jit, static_argnames=_STAGE1_STATIC)
 def generate_candidates(
     index_embs: jax.Array,      # (C, L, M)
     index_mask: jax.Array,      # (C, L)
@@ -102,73 +112,99 @@ def generate_candidates(
     kprime = min(kprime, C * L)   # a tiny shard can't yield k' neighbors
     top_vals, hit_docs = token_topk(index_embs, index_mask, query, kprime,
                                      chunk_docs)                  # (T, k')
-    return candidates_from_hits(top_vals, hit_docs, C, quota,
-                                max_candidates=max_candidates,
-                                support=support)
+    with jax.named_scope("stage1_candidates"):
+        return candidates_from_hits(top_vals, hit_docs, C, quota,
+                                    max_candidates=max_candidates,
+                                    support=support)
+
+
+@functools.partial(jax.jit, static_argnames=_STAGE1_STATIC)
+def generate_candidates_batch(
+    index_embs: jax.Array,      # (C, L, M)
+    index_mask: jax.Array,      # (C, L)
+    queries: jax.Array,         # (B, T, M)
+    quotas=None,                # (B,) i32 traced caps on |candidates|, or None
+    *,
+    kprime: int = 10,
+    max_candidates: int = 256,
+    support: Tuple[float, float] = (0.0, 1.0),
+    chunk_docs: int = STAGE1_CHUNK_DOCS,
+) -> CandidateSet:
+    """``generate_candidates`` for each of B queries, fields (B, ...): one
+    scan over all B*T query-token rows, then each query's candidate set."""
+    C, L, _ = index_embs.shape
+    kprime = min(kprime, C * L)
+    top_vals, hit_docs = token_topk(index_embs, index_mask, queries, kprime,
+                                     chunk_docs)               # (B, T, k')
+    with jax.named_scope("stage1_candidates"):
+        return jax.vmap(functools.partial(
+            candidates_from_hits, n_docs=C, max_candidates=max_candidates,
+            support=support))(top_vals, hit_docs, quota=quotas)
 
 
 def candidates_from_hits(top_vals, hit_docs, n_docs: int, quota=None, *,
                          max_candidates: int,
                          support: Tuple[float, float]) -> CandidateSet:
     """Eq. 15 candidate set from per-token top-k' hits (values and owning
-    doc ids, (T, k') each, best first) over an ``n_docs``-document index."""
-    with jax.named_scope("stage1_candidates"):
-        C = n_docs
-        T, kprime = top_vals.shape
-        s_kprime = top_vals[:, kprime - 1]
+    doc ids, (T, k') each, best first) over an ``n_docs``-document index.
+    Callers open the ``stage1_candidates`` scope around it, outside any
+    vmap, so the device profile groups its operations under that name."""
+    C = n_docs
+    T, kprime = top_vals.shape
+    s_kprime = top_vals[:, kprime - 1]
 
-        # Candidate set = union of hit docs. If the union exceeds
-        # max_candidates, keep the docs with the HIGHEST best-hit similarity
-        # (arbitrary-id truncation would silently drop strong candidates).
-        doc_best = jnp.full((C,), _NEG).at[hit_docs.reshape(-1)].max(
-            top_vals.reshape(-1))
-        best_vals, best_ids = jax.lax.top_k(doc_best, min(max_candidates, C))
-        if C < max_candidates:           # pad to the static candidate count
-            pad = max_candidates - C
-            best_vals = jnp.pad(best_vals, (0, pad), constant_values=_NEG)
-            best_ids = jnp.pad(best_ids, (0, pad), constant_values=0)
-        sel = best_vals > _NEG / 2
-        if quota is not None:
-            # Skew-aware routing cap: best_vals is descending, so rank ==
-            # position; keep only the strongest ``quota`` candidates.
-            sel = sel & (jnp.arange(max_candidates) < quota)
-        sentinel = jnp.iinfo(jnp.int32).max
-        sorted_slots = jnp.sort(jnp.where(sel, best_ids, sentinel))
-        # Keep the sentinel-padded array around: it stays ascending, which the
-        # searchsorted hit-lookup below requires (-1 padding would break the
-        # sort order and silently drop exact b-values for high doc ids).
-        cands = jnp.where(sorted_slots == sentinel, -1,
-                          sorted_slots).astype(jnp.int32)
-        doc_mask = cands >= 0
+    # Candidate set = union of hit docs. If the union exceeds
+    # max_candidates, keep the docs with the HIGHEST best-hit similarity
+    # (arbitrary-id truncation would silently drop strong candidates).
+    doc_best = jnp.full((C,), _NEG).at[hit_docs.reshape(-1)].max(
+        top_vals.reshape(-1))
+    best_vals, best_ids = jax.lax.top_k(doc_best, min(max_candidates, C))
+    if C < max_candidates:           # pad to the static candidate count
+        pad = max_candidates - C
+        best_vals = jnp.pad(best_vals, (0, pad), constant_values=_NEG)
+        best_ids = jnp.pad(best_ids, (0, pad), constant_values=0)
+    sel = best_vals > _NEG / 2
+    if quota is not None:
+        # Skew-aware routing cap: best_vals is descending, so rank ==
+        # position; keep only the strongest ``quota`` candidates.
+        sel = sel & (jnp.arange(max_candidates) < quota)
+    sentinel = jnp.iinfo(jnp.int32).max
+    sorted_slots = jnp.sort(jnp.where(sel, best_ids, sentinel))
+    # Keep the sentinel-padded array around: it stays ascending, which the
+    # searchsorted hit-lookup below requires (-1 padding would break the
+    # sort order and silently drop exact b-values for high doc ids).
+    cands = jnp.where(sorted_slots == sentinel, -1,
+                      sorted_slots).astype(jnp.int32)
+    doc_mask = cands >= 0
 
-        a_lo, b_hi = support
-        a = jnp.full((max_candidates, T), jnp.float32(a_lo))
-        # Default upper bound: the k'-th neighbor similarity per token
-        # (Eq. 15).
-        b = jnp.broadcast_to(jnp.maximum(s_kprime, a_lo)[None, :],
-                             (max_candidates, T)).astype(jnp.float32)
+    a_lo, b_hi = support
+    a = jnp.full((max_candidates, T), jnp.float32(a_lo))
+    # Default upper bound: the k'-th neighbor similarity per token
+    # (Eq. 15).
+    b = jnp.broadcast_to(jnp.maximum(s_kprime, a_lo)[None, :],
+                         (max_candidates, T)).astype(jnp.float32)
 
-        # Hit cells: exact h value via scatter-max into candidate rows.
-        pos = jnp.searchsorted(sorted_slots, hit_docs)             # (T, k')
-        pos = jnp.clip(pos, 0, max_candidates - 1)
-        is_cand = jnp.take(sorted_slots, pos) == hit_docs
-        t_grid = jnp.broadcast_to(jnp.arange(T)[:, None], hit_docs.shape)
-        safe_pos = jnp.where(is_cand, pos, max_candidates - 1)
+    # Hit cells: exact h value via scatter-max into candidate rows.
+    pos = jnp.searchsorted(sorted_slots, hit_docs)             # (T, k')
+    pos = jnp.clip(pos, 0, max_candidates - 1)
+    is_cand = jnp.take(sorted_slots, pos) == hit_docs
+    t_grid = jnp.broadcast_to(jnp.arange(T)[:, None], hit_docs.shape)
+    safe_pos = jnp.where(is_cand, pos, max_candidates - 1)
 
-        known_vals = jnp.full((max_candidates, T), _NEG)
-        known_vals = known_vals.at[safe_pos, t_grid].max(
-            jnp.where(is_cand, top_vals, _NEG))
-        known_mask = known_vals > _NEG / 2
-        known_vals = jnp.where(known_mask, known_vals, 0.0)
+    known_vals = jnp.full((max_candidates, T), _NEG)
+    known_vals = known_vals.at[safe_pos, t_grid].max(
+        jnp.where(is_cand, top_vals, _NEG))
+    known_mask = known_vals > _NEG / 2
+    known_vals = jnp.where(known_mask, known_vals, 0.0)
 
-        b = jnp.where(known_mask, known_vals, b)
-        b = jnp.clip(b, a_lo, b_hi)
-        a = jnp.where(doc_mask[:, None], a, 0.0)
-        b = jnp.where(doc_mask[:, None], b, 0.0)
+    b = jnp.where(known_mask, known_vals, b)
+    b = jnp.clip(b, a_lo, b_hi)
+    a = jnp.where(doc_mask[:, None], a, 0.0)
+    b = jnp.where(doc_mask[:, None], b, 0.0)
 
-        return CandidateSet(doc_ids=cands, doc_mask=doc_mask, a=a, b=b,
-                            known_mask=known_mask & doc_mask[:, None],
-                            known_vals=known_vals, s_kprime=s_kprime)
+    return CandidateSet(doc_ids=cands, doc_mask=doc_mask, a=a, b=b,
+                        known_mask=known_mask & doc_mask[:, None],
+                        known_vals=known_vals, s_kprime=s_kprime)
 
 
 def generic_bounds(n: int, t: int,

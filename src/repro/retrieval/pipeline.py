@@ -27,7 +27,8 @@ from repro.core.baselines import doc_top_margin, doc_uniform, exact_topk
 from repro.data.synthetic import RetrievalDataset
 from repro.kernels import ref as kref
 from repro.kernels.ops import maxsim_op
-from repro.retrieval.ann import CandidateSet, generate_candidates, generic_bounds
+from repro.retrieval.ann import (CandidateSet, generate_candidates,
+                                 generate_candidates_batch, generic_bounds)
 from repro.retrieval.index import TokenIndex, build_index
 from repro.retrieval.service import rerank_bandit_step, rerank_dense_step
 
@@ -197,9 +198,9 @@ def serve_queries(
     bandit = bandit or BanditConfig(k=k)
     queries = jnp.asarray(queries, jnp.float32)
 
-    cand = jax.vmap(lambda qq: generate_candidates(
-        embs, mask, qq, kprime=kprime, max_candidates=max_candidates,
-        support=bandit.support))(queries)
+    cand = generate_candidates_batch(embs, mask, queries, kprime=kprime,
+                                     max_candidates=max_candidates,
+                                     support=bandit.support)
     key = jax.random.key(seed)
     if flavor == "dense":
         scores, gids, frac, stats = rerank_dense_step(

@@ -38,7 +38,7 @@ from repro.kernels.ops import (fused_reveal_op, gather_maxsim_op,
 from repro.kernels.quant import QuantTokens, corpus_reshape
 from repro.launch.mesh import auto_axes
 from repro.retrieval.ann import (STAGE1_CHUNK_DOCS, candidates_from_hits,
-                                 generate_candidates, token_topk)
+                                 generate_candidates_batch, token_topk)
 from repro.retrieval.corpus import gather_tokens, route_mass, route_quotas
 from repro.retrieval.sharded import corpus_embs_spec
 
@@ -1023,7 +1023,7 @@ def make_routed_serving_step(mesh: Mesh, flavor: str = "bandit", *,
                         block_docs=block_docs, block_tokens=block_tokens,
                         max_rounds=max_rounds, max_block_docs=max_block_docs,
                         max_block_tokens=max_block_tokens)
-    gen = functools.partial(generate_candidates, kprime=kprime,
+    gen = functools.partial(generate_candidates_batch, kprime=kprime,
                             max_candidates=n_local, support=support)
 
     def step(corpus_embs, corpus_mask, centroids, shard_mass, queries,
@@ -1061,11 +1061,7 @@ def make_routed_serving_step(mesh: Mesh, flavor: str = "bandit", *,
             # Shard-local stage-1: per-query-token kNN over this shard's
             # own (C_loc * L, M) tokens. Pad rows carry all-False masks so
             # they can never become candidates.
-            if my_quota is None:
-                cand = jax.vmap(lambda qq: gen(c_embs, c_mask, qq))(q)
-            else:
-                cand = jax.vmap(
-                    lambda qq, nq: gen(c_embs, c_mask, qq, nq))(q, my_quota)
+            cand = gen(c_embs, c_mask, q, my_quota)
 
             gids = _shard_global_ids(cand.doc_ids, c_loc, every, vd)
             valid = (gids >= 0) & hl[shard_ix]
@@ -1153,9 +1149,7 @@ def make_sharded_stage1(mesh: Mesh, *, kprime: int = 8,
     def local_hits(c_embs, c_mask, q):
         c_loc, L, _ = c_embs.shape
         kp = min(kprime, c_loc * L)
-        vals, docs = jax.vmap(
-            lambda qq: token_topk(c_embs, c_mask, qq, kp,
-                                  STAGE1_CHUNK_DOCS))(q)
+        vals, docs = token_topk(c_embs, c_mask, q, kp, STAGE1_CHUNK_DOCS)
         docs = docs + _shard_index(every) * c_loc
         return (jax.lax.all_gather(vals, every, axis=2, tiled=True),
                 jax.lax.all_gather(docs, every, axis=2, tiled=True))
@@ -1176,6 +1170,7 @@ def make_sharded_stage1(mesh: Mesh, *, kprime: int = 8,
             cs = candidates_from_hits(v, d, C, max_candidates=max_candidates,
                                       support=support)
             return cs.doc_ids, cs.a, cs.b
-        return jax.vmap(one)(vals, docs)
+        with jax.named_scope("stage1_candidates"):
+            return jax.vmap(one)(vals, docs)
 
     return stage1

@@ -17,8 +17,8 @@ are first-class (``engine.compiled_buckets``, ``metrics.compiles``) so tests
 can assert the no-recompile property instead of trusting it.
 
 Requests either carry a stage-1 candidate list (``cand_ids``) or the engine
-runs its own stage-1 ANN (``repro.retrieval.ann.generate_candidates``,
-vmapped per batch, also bucket-compiled) — the ANN path additionally yields
+runs its own stage-1 ANN (``repro.retrieval.ann.generate_candidates_batch``,
+one scan per batch, also bucket-compiled) — the ANN path additionally yields
 Eq. 15 per-cell bounds, which is what makes the bandit flavor effective.
 
 The LM decode engine that used to live here moved to ``repro.serve.lm``.
@@ -53,7 +53,7 @@ from repro.kernels.ops import autotune_op
 from repro.kernels.quant import (CORPUS_FORMATS, corpus_nbytes,
                                  format_ordinal)
 from repro.launch.mesh import make_mesh
-from repro.retrieval.ann import generate_candidates
+from repro.retrieval.ann import generate_candidates_batch
 from repro.retrieval.corpus import Corpus, build_corpus
 from repro.retrieval.service import (init_stream_state,
                                      make_routed_serving_step,
@@ -825,12 +825,10 @@ class RetrievalEngine:
                                              support=support)
             else:
                 def stage1(ce, cm, q):
-                    def one(qq):
-                        cs = generate_candidates(ce, cm, qq, kprime=kp,
-                                                 max_candidates=nb,
-                                                 support=support)
-                        return cs.doc_ids, cs.a, cs.b
-                    return jax.vmap(one)(q)
+                    cs = generate_candidates_batch(ce, cm, q, kprime=kp,
+                                                   max_candidates=nb,
+                                                   support=support)
+                    return cs.doc_ids, cs.a, cs.b
 
             args = (self.corpus_embs, self.corpus_mask,
                     SDS((B, tb, M), jnp.float32))

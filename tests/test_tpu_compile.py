@@ -22,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
 from repro.kernels.quant import QuantTokens
+from repro.retrieval.ann import STAGE1_CHUNK_DOCS, generate_candidates_batch
 from repro.retrieval.service import make_serving_step
 
 HBM_BYTES = 16 << 30
@@ -123,3 +124,40 @@ def test_bandit_rerank_step_compiles_for_v5e(one_chip, monkeypatch):
     assert re.search(r"%fused_reveal\.\d+ = [^\n]*custom-call", text)
     for scope in ("frontier_init", "frontier_round"):
         assert f"/{scope}/" in text, scope
+
+
+def test_stage1_chunk_topk_lowers_to_tpu_topk(one_chip):
+    """The engine's stage-1 program at the text cell's shape: a resident
+    bf16 index of 131072 passages, 8 queries of 32 tokens, k'=10 and 320
+    candidates. Each chunk's top-k' over its STAGE1_CHUNK_DOCS * L token
+    columns must be the TPU's TopK, never a sort of those columns (a
+    vmapped, rank-3 top_k lowers to one, and sorting every chunk in full
+    costs many times the chunk's dot)."""
+    C, L, B, T = 131072, 128, 8, 32
+
+    def stage1(ce, cm, q):
+        cs = generate_candidates_batch(ce, cm, q, kprime=10,
+                                       max_candidates=320)
+        return cs.doc_ids, cs.a, cs.b
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(stage1).lower(
+        sds((C, L, M), jnp.bfloat16), sds((C, L), jnp.bool_),
+        sds((B, T, M), jnp.float32)).compile()
+    text = compiled.as_text()
+    width = STAGE1_CHUNK_DOCS * L
+    lines = text.splitlines()
+    # An instruction's shapes are what precedes its opcode.
+    sorts = [ln.split(" sort(")[0] for ln in lines if " sort(" in ln]
+    assert sorts, "expected the small merge and candidate sorts"
+    for shapes in sorts:
+        assert not re.search(rf"\[[\d,]*\b{width}\]", shapes), shapes
+    topks = [re.search(r"= \(f32\[([\d,]+)\]", ln).group(1) for ln in lines
+             if 'custom_call_target="TopK"' in ln]
+    # Both chunk selections (the first chunk's and the scan body's) are a
+    # TopK of k'=10 over all B*T query-token rows.
+    assert topks.count(f"{B * T},10") == 2, topks
+    # A vmapped per-query scan, which sorts, needs 1343019520 bytes of temp.
+    assert compiled.memory_analysis().temp_size_in_bytes < 1343019520
