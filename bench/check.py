@@ -3,10 +3,13 @@
 Each number is compared with its limit, a number passes at or below it:
 
 * ``lost``: requests submitted in the window and not answered exactly
-  once, plus answers that carry an error;
+  once, plus answers that carry an error; in a mix that fails a shard,
+  also answers whose coverage misstates that shard's health when their
+  batch read it (``harness.expected``);
 * ``recompiles``: programs compiled after ``warmup()``;
 * ``foreign``: answers with a doc id outside the request's candidates
   (as the reference rebuilds them), a repeated id, or fewer than k ids;
+  an answer served with a shard down may hold none of that shard's docs;
 * ``miss_share``: 1 - the mean top-k overlap with the reference's f32
   MaxSim top-k over the same candidates: the share of returned ids that
   the exhaustive top-k does not hold. A scorer that skips part of each
@@ -32,11 +35,30 @@ import numpy as np
 EXACT_RTOL = 1e-5
 
 
+def shard_of(doc: int, docs_per_shard: int) -> int:
+    """The shard that holds ``doc`` in an index placed as contiguous blocks
+    of ``docs_per_shard`` rows."""
+    return doc // docs_per_shard
+
+
+class OffShard:
+    """The doc ids that do not lie on ``shard``: the candidate set of an
+    answer served with that shard down, where the reference has not
+    rebuilt its candidates."""
+
+    def __init__(self, shard: int, docs_per_shard: int):
+        self.shard, self.docs_per_shard = shard, docs_per_shard
+
+    def __contains__(self, doc: int) -> bool:
+        return shard_of(doc, self.docs_per_shard) != self.shard
+
+
 def answer_numbers(answers: Sequence, ref_scores: Sequence[Dict[int, float]],
                    cand_sets: Sequence[Optional[set]], k: int) -> Dict:
     """``answers[i]`` = (ids, scores) of request i; ``ref_scores[i]`` the
     reference score of each of its candidates (None: not checked against
-    the reference); ``cand_sets[i]`` its candidate ids (None: unknown).
+    the reference); ``cand_sets[i]`` its candidate ids, or any container of
+    the ids it may hold (None: unknown).
     ``overlaps`` holds each request's top-k overlap (None: not checked)."""
     foreign, overlaps, inexact, scored = 0, [], 0, 0
     per_request: List[Optional[float]] = []

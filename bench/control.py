@@ -47,7 +47,7 @@ def control_numbers(cell: harness.Cell, seed: int) -> List[Dict]:
     cfg, mix = cell.config, cell.mix
     k = cfg["k"]
     rng = np.random.default_rng(seed)
-    corpus = make_corpus(cfg, seed)
+    corpus = make_corpus(cfg, seed, cell.workload["chips"])
     templates = make_templates(corpus, mix, rng)
     used = list(range(len(templates)))
     ref, cand_sets = harness.reference_answers(cell, corpus, templates, used,

@@ -9,6 +9,12 @@ distractors of each query carry tokens pulled hard towards the query's
 topic. The host draws only the per-document plan (topic, length, planting)
 and the queries; the token rows are drawn and written in place by one
 jitted program, chunk by chunk, in the type they are served in (bf16).
+
+A deployment over several chips holds its index as the engine shards it:
+contiguous blocks of rows over a one-axis ``data`` mesh. Each chip then
+runs the fill program for its own block, and no row crosses the host or
+another chip; every chunk keeps the key of its global index, so the bytes
+are those of the one-chip index at any chip count.
 """
 from __future__ import annotations
 
@@ -18,6 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 N_TOPICS = 32
 TOPIC_STRENGTH = 0.7         # planted relevant tokens' pull toward the topic
@@ -67,10 +74,11 @@ def _draw_docs(key, topics, doc_topic, doc_lens, p_topic, p_strength,
     return e.astype(jnp.bfloat16), mask
 
 
-@functools.partial(jax.jit, static_argnames=("doc_len", "dim", "chunk"))
-def _fill(key_seed, topics, plan, *, doc_len: int, dim: int, chunk: int):
-    """All token rows in one program: a loop over chunks that draws each
-    chunk and writes it into the index in place."""
+def _fill_rows(key_seed, first_chunk, topics, plan, *, doc_len: int,
+               dim: int, chunk: int):
+    """The token rows of ``plan``'s documents in one program: a loop over
+    chunks that draws each chunk (keyed by its global index, ``first_chunk``
+    onwards) and writes it into the block in place."""
     n_docs = plan[0].shape[0]
     base = jax.random.key(key_seed)
 
@@ -78,8 +86,8 @@ def _fill(key_seed, topics, plan, *, doc_len: int, dim: int, chunk: int):
         embs, mask = carry
         start = i * chunk
         part = [jax.lax.dynamic_slice_in_dim(a, start, chunk) for a in plan]
-        e, m = _draw_docs(jax.random.fold_in(base, i), topics, *part,
-                          doc_len=doc_len)
+        e, m = _draw_docs(jax.random.fold_in(base, first_chunk + i), topics,
+                          *part, doc_len=doc_len)
         return (jax.lax.dynamic_update_slice_in_dim(embs, e, start, 0),
                 jax.lax.dynamic_update_slice_in_dim(mask, m, start, 0))
 
@@ -88,19 +96,47 @@ def _fill(key_seed, topics, plan, *, doc_len: int, dim: int, chunk: int):
     return jax.lax.fori_loop(0, n_docs // chunk, body, init)
 
 
-def make_corpus(cfg: dict, seed: int) -> Corpus:
+@functools.lru_cache(maxsize=None)
+def _filler(device=None):
+    """The fill program with its block placed on ``device`` (None: the
+    default device)."""
+    out = None if device is None else jax.sharding.SingleDeviceSharding(
+        device)
+    return jax.jit(_fill_rows, static_argnames=("doc_len", "dim", "chunk"),
+                   out_shardings=out)
+
+
+def index_mesh(cfg: dict, chips: int):
+    """The mesh the engine builds for the configuration's ``mesh_axes``, or
+    None for one chip. Refuses a configuration that does not name one
+    ``data`` axis over exactly the cell's chips, or whose index does not
+    split into whole chunks on each chip."""
+    axes = [tuple(a) for a in cfg.get("engine", {}).get("mesh_axes", [])]
+    want = [("data", chips)] if chips > 1 else []
+    if axes != want:
+        raise ValueError(f"engine.mesh_axes {axes} does not match the cell's "
+                         f"{chips} chip(s): expected {want}")
+    n_docs, chunk = cfg["corpus_docs"], cfg["corpus"]["chunk_docs"]
+    if n_docs % (chips * chunk):
+        raise ValueError(f"corpus_docs {n_docs} is not a multiple of "
+                         f"{chips} chip(s) x chunk_docs {chunk}")
+    if chips == 1:
+        return None
+    from repro.launch.mesh import make_mesh
+    return make_mesh((chips,), ("data",))
+
+
+def make_corpus(cfg: dict, seed: int, chips: int = 1) -> Corpus:
     """The index a configuration describes (``corpus_docs`` documents of
     ``min_doc_tokens``..``doc_tokens`` tokens of width ``dim``) and a pool
     of ``planted_queries`` queries of ``query_tokens`` tokens, from
-    ``seed``."""
+    ``seed``; over ``chips`` chips, each chip's block of rows made on it."""
+    mesh = index_mesh(cfg, chips)
     n_docs, doc_len, dim = (cfg["corpus_docs"], cfg["doc_tokens"],
                             cfg["dim"])
     gen = cfg["corpus"]
     n_q, chunk = gen["planted_queries"], gen["chunk_docs"]
     n_rel, n_dis = gen["relevant_per_query"], gen["distractors_per_query"]
-    if n_docs % chunk:
-        raise ValueError(f"corpus_docs {n_docs} is not a multiple of "
-                         f"chunk_docs {chunk}")
     # The topic directions are part of the deployment (its configuration's
     # ``topic_seed``); the seed draws the documents and queries over them,
     # so that seeds change which data is asked about, not how hard it is.
@@ -141,8 +177,35 @@ def make_corpus(cfg: dict, seed: int) -> Corpus:
                          + (1 - qmix) * qn * 0.4)
 
     plan = (doc_topic, doc_lens, p_topic, p_strength, p_noise, p_count)
-    embs, mask = _fill(np.uint32(rng.integers(2**31)), jnp.asarray(topics),
-                       tuple(jnp.asarray(a) for a in plan),
-                       doc_len=doc_len, dim=dim, chunk=chunk)
+    key_seed = np.uint32(rng.integers(2**31))
+    sizes = dict(doc_len=doc_len, dim=dim, chunk=chunk)
+    if mesh is None:
+        embs, mask = _filler()(key_seed, np.int32(0), jnp.asarray(topics),
+                               tuple(jnp.asarray(a) for a in plan), **sizes)
+    else:
+        embs, mask = _fill_sharded(mesh, key_seed, topics, plan, sizes)
     return Corpus(embs=embs, mask=mask, doc_topic=doc_topic,
                   queries=queries, query_topic=query_topic, planted=planted)
+
+
+def _fill_sharded(mesh, key_seed, topics, plan, sizes):
+    """Each device of ``mesh`` fills the rows its ``data`` block holds;
+    the blocks are assembled, without a copy, into the index placed as
+    ``repro.retrieval.sharded.shard_corpus`` places it."""
+    n_docs, chunk = plan[0].shape[0], sizes["chunk"]
+    shape = (n_docs, sizes["doc_len"], sizes["dim"])
+    e_sharding = NamedSharding(mesh, P("data", None, None))
+    m_sharding = NamedSharding(mesh, P("data", None))
+    e_parts, m_parts = [], []
+    for dev, idx in e_sharding.addressable_devices_indices_map(shape).items():
+        lo, hi, _ = idx[0].indices(n_docs)
+        e, m = _filler(dev)(key_seed, np.int32(lo // chunk),
+                            jax.device_put(topics, dev),
+                            tuple(jax.device_put(a[lo:hi], dev)
+                                  for a in plan), **sizes)
+        e_parts.append(e)
+        m_parts.append(m)
+    return (jax.make_array_from_single_device_arrays(shape, e_sharding,
+                                                     e_parts),
+            jax.make_array_from_single_device_arrays(shape[:2], m_sharding,
+                                                     m_parts))

@@ -70,7 +70,6 @@ def main(argv=None) -> int:
         return 2
     result = harness.run_cell(
         cell, args.seed, args.seconds, bool(args.trace), t_start=T_START,
-        chips=cell.workload["chips"],
         peaks=peaks["devices"][jax.devices()[0].device_kind])
     print(json.dumps(result), flush=True)
     check.report(result["check"])

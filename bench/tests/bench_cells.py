@@ -77,3 +77,43 @@ def run(root, workload, seed=3, seconds=1.0, trace=False, **kw):
     cell = harness.load_cell(root, workload)
     return harness.run_cell(cell, seed, seconds, trace,
                             t_start=time.monotonic(), peaks=PEAKS, **kw)
+
+
+def routed_root(tmp) -> str:
+    """``small_root`` plus a 4-shard routed twin of a sharded deployment:
+    the ``small-routed`` configuration (4 x 256 passages, one ``data`` axis
+    over 4 devices, shard-local stage-1, no quotas) under a Zipf-skewed
+    closed loop, ``small-routed``, and the same with shard 1 failed for
+    part of the window, ``small-routed-failover``. At this size the
+    bandit's answers are exact (``miss_share`` reads 0), so the twin's
+    ``miss_share`` limit is 0.05: a shard left out costs about a quarter of
+    each top-k. Run it where JAX has 4 devices (``four_devices.py``)."""
+    root = small_root(tmp)
+    cfg = load(os.path.join(root, "bench", "configs", "small.json"))
+    cfg.update(name="small-routed", corpus_docs=1024)
+    cfg["engine"].update(mesh_axes=[["data", 4]], stage1="local",
+                         stage1_total=0)
+    write(os.path.join(root, "bench", "configs", "small-routed.json"), cfg)
+    mix = load(os.path.join(root, "bench", "traffic", "small-backlog.json"))
+    mix.update(topic_zipf=1.0)
+    mix["limits"]["miss_share"] = 0.05
+    write(os.path.join(root, "bench", "traffic", "small-routed.json"), mix)
+    mix.update(shard_failure={"shard": 1, "at_s": 0.5, "for_s": 1.0})
+    write(os.path.join(root, "bench", "traffic",
+                       "small-routed-failover.json"), mix)
+    spec = load(os.path.join(root, "BENCHMARK.json"))
+    spec["configs"].append(dict(name="small-routed", source="small copy",
+                                file="bench/configs/small-routed.json",
+                                reduced=[], why="CPU test"))
+    for name in ROUTED:
+        spec["workloads"].append(dict(name=name, config="small-routed",
+                                      traffic=name, chips=4,
+                                      why="CPU test"))
+    for m in spec["end_to_end"]:
+        if m["name"] == "throughput_qps":
+            m["workloads"] += ROUTED
+    write(os.path.join(root, "BENCHMARK.json"), spec)
+    return root
+
+
+ROUTED = ["small-routed", "small-routed-failover"]

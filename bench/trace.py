@@ -59,13 +59,19 @@ class Reduction:
 
     @property
     def busy_s(self) -> float:
-        """Seconds in which a program ran, averaged over the devices."""
-        return sum(hi - lo for lo, hi in self.busy_intervals()) / 1e9 \
-            / max(self.n_devices, 1)
+        """Seconds in which a program ran on a device, averaged over the
+        devices."""
+        n = max(self.n_devices, 1)
+        return sum(hi - lo for d in range(n)
+                   for lo, hi in self.busy_intervals(d)) / 1e9 / n
 
-    def busy_intervals(self) -> List[Tuple[float, float]]:
+    def busy_intervals(self, device: Optional[int] = None
+                       ) -> List[Tuple[float, float]]:
+        """Intervals in which a program ran on ``device`` (None: on any
+        device)."""
         ivs = sorted((max(e.start, self.window[0]), min(e.end, self.window[1]))
-                     for e in self.programs)
+                     for e in self.programs
+                     if device is None or e.device == device)
         out: List[List[float]] = []
         for lo, hi in ivs:
             if hi <= lo:
@@ -89,7 +95,8 @@ class Reduction:
                 and e.start >= self.window[0] and e.end <= self.window[1]]
 
     def gaps(self) -> List[Tuple[float, float]]:
-        """Idle intervals of the window, longest first."""
+        """Intervals of the window in which no device ran a program,
+        longest first."""
         out, t = [], self.window[0]
         for lo, hi in self.busy_intervals():
             if lo > t:
