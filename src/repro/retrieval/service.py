@@ -1043,69 +1043,77 @@ def make_routed_serving_step(mesh: Mesh, flavor: str = "bandit", *,
             B, T = q.shape[0], q.shape[1]
             c_loc = c_embs.shape[0]
 
-            # Centroid routing (replicated state => identical table on
-            # every shard; each reads its own column). A failed shard's
-            # quota mass is re-routed onto healthy shards HERE, so
-            # failover costs zero extra candidates system-wide.
-            m = route_mass(q, cents, mass)                    # (B, S)
-            if n_total:
-                quota = route_quotas(m, n_total,
-                                     healthy=hl if use_healthy else None)
-                my_quota = quota[:, shard_ix]                 # (B,)
-                share = quota.astype(jnp.float32) / jnp.float32(n_total)
-            else:
-                my_quota = None
-                share = jnp.full((B, n_shards), 1.0 / n_shards, jnp.float32)
-            my_share = share[:, shard_ix]                     # (B,)
+            # Name scopes for XProf's op profile: stage-1 (routing and
+            # the shard's own scan), the rerank, and the merge's
+            # collectives (the all-gather and two psums).
+            with jax.named_scope("routed_stage1"):
+                # Centroid routing (replicated state => identical table
+                # on every shard; each reads its own column). A failed
+                # shard's quota mass is re-routed onto healthy shards
+                # HERE, so failover costs zero extra candidates
+                # system-wide.
+                m = route_mass(q, cents, mass)                    # (B, S)
+                if n_total:
+                    quota = route_quotas(m, n_total,
+                                         healthy=hl if use_healthy else None)
+                    my_quota = quota[:, shard_ix]                 # (B,)
+                    share = quota.astype(jnp.float32) / jnp.float32(n_total)
+                else:
+                    my_quota = None
+                    share = jnp.full((B, n_shards), 1.0 / n_shards,
+                                     jnp.float32)
+                my_share = share[:, shard_ix]                     # (B,)
 
-            # Shard-local stage-1: per-query-token kNN over this shard's
-            # own (C_loc * L, M) tokens. Pad rows carry all-False masks so
-            # they can never become candidates.
-            cand = gen(c_embs, c_mask, q, my_quota)
+                # Shard-local stage-1: per-query-token kNN over this
+                # shard's own (C_loc * L, M) tokens. Pad rows carry
+                # all-False masks so they can never become candidates.
+                cand = gen(c_embs, c_mask, q, my_quota)
 
-            gids = _shard_global_ids(cand.doc_ids, c_loc, every, vd)
-            valid = (gids >= 0) & hl[shard_ix]
-            gids = jnp.where(valid, gids, -1)
-            docs, dmask = gather_candidates(c_embs, c_mask, cand.doc_ids)
-            dmask = dmask & valid[:, :, None]
-            n_cells = (jnp.sum(valid, axis=1) * T).astype(jnp.float32)
+                gids = _shard_global_ids(cand.doc_ids, c_loc, every, vd)
+                valid = (gids >= 0) & hl[shard_ix]
+                gids = jnp.where(valid, gids, -1)
+            with jax.named_scope("routed_rerank"):
+                docs, dmask = gather_candidates(c_embs, c_mask, cand.doc_ids)
+                dmask = dmask & valid[:, :, None]
+                n_cells = (jnp.sum(valid, axis=1) * T).astype(jnp.float32)
 
-            if flavor == "dense":
-                s = _local_maxsim_scores(docs, dmask, q)
-                finite = jnp.isfinite(s)
-                quar = jnp.sum(valid & ~finite).astype(jnp.float32)
-                s = jnp.where(valid & finite, s, _NEG)
-                best, pos = jax.lax.top_k(s, k_shard)
-                bg = jnp.take_along_axis(gids, pos, axis=1)
-                n_rev = n_cells
-                stats4 = jnp.stack([jnp.float32(1.0), jnp.float32(0.0),
-                                    jnp.float32(0.0), quar])
-            else:
-                key = jax.random.fold_in(jax.random.key(base_seed), sd)
-                key = jax.random.fold_in(key, shard_ix)
-                keys = jax.random.split(key, B)
-                a_l = jnp.where(valid[:, :, None], cand.a, 0.0)
-                b_l = jnp.where(valid[:, :, None], cand.b, 0.0)
-                kw = {}
-                n_known = jnp.zeros((B,), jnp.float32)
-                if prereveal_ann:
-                    pr = cand.known_mask & valid[:, :, None]
-                    kw = dict(prereveal=pr, prereveal_vals=cand.known_vals)
-                    n_known = jnp.sum(pr, axis=(1, 2)).astype(jnp.float32)
-                if knobs:
-                    kw.update(alpha_scale=a_s, round_cap=r_c)
-                _, bg, cov, stats4, won = rerank(
-                    docs, dmask, q, gids, a_l, b_l, keys, cfg, **kw)
-                # Reveal accounting: prereveal cells were free (stage 1
-                # already computed them), so they don't count as work.
-                best, n_rev = _exact_winners(
-                    c_embs, c_mask, q, cand.doc_ids, gids, bg,
-                    jnp.maximum(cov * n_cells - n_known, 0.0), won)
+                if flavor == "dense":
+                    s = _local_maxsim_scores(docs, dmask, q)
+                    finite = jnp.isfinite(s)
+                    quar = jnp.sum(valid & ~finite).astype(jnp.float32)
+                    s = jnp.where(valid & finite, s, _NEG)
+                    best, pos = jax.lax.top_k(s, k_shard)
+                    bg = jnp.take_along_axis(gids, pos, axis=1)
+                    n_rev = n_cells
+                    stats4 = jnp.stack([jnp.float32(1.0), jnp.float32(0.0),
+                                        jnp.float32(0.0), quar])
+                else:
+                    key = jax.random.fold_in(jax.random.key(base_seed), sd)
+                    key = jax.random.fold_in(key, shard_ix)
+                    keys = jax.random.split(key, B)
+                    a_l = jnp.where(valid[:, :, None], cand.a, 0.0)
+                    b_l = jnp.where(valid[:, :, None], cand.b, 0.0)
+                    kw = {}
+                    n_known = jnp.zeros((B,), jnp.float32)
+                    if prereveal_ann:
+                        pr = cand.known_mask & valid[:, :, None]
+                        kw = dict(prereveal=pr, prereveal_vals=cand.known_vals)
+                        n_known = jnp.sum(pr, axis=(1, 2)).astype(jnp.float32)
+                    if knobs:
+                        kw.update(alpha_scale=a_s, round_cap=r_c)
+                    _, bg, cov, stats4, won = rerank(
+                        docs, dmask, q, gids, a_l, b_l, keys, cfg, **kw)
+                    # Reveal accounting: prereveal cells were free (stage 1
+                    # already computed them), so they don't count as work.
+                    best, n_rev = _exact_winners(
+                        c_embs, c_mask, q, cand.doc_ids, gids, bg,
+                        jnp.maximum(cov * n_cells - n_known, 0.0), won)
 
-            tot_rev = jax.lax.psum(n_rev, every)
-            tot_cells = jax.lax.psum(n_cells, every)
-            frac = tot_rev / jnp.maximum(tot_cells, 1.0)
-            g_best, g_ids = _merge_scorecards(best, bg, every, topk)
+            with jax.named_scope("routed_merge"):
+                tot_rev = jax.lax.psum(n_rev, every)
+                tot_cells = jax.lax.psum(n_cells, every)
+                frac = tot_rev / jnp.maximum(tot_cells, 1.0)
+                g_best, g_ids = _merge_scorecards(best, bg, every, topk)
             # Column order keeps quarantine LAST so the routing-skew
             # columns stay at the indices metrics consumers already read.
             stats_loc = jnp.concatenate(

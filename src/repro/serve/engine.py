@@ -293,6 +293,9 @@ class BatchRecord:
     # shards; uniform = 1/n_shards). The skew signal metrics.summary()
     # aggregates into routed_quota_share_mean / routed_skew.
     shard_quota_share: Optional[Tuple[float, ...]] = None
+    # Mesh engines only: how many shards were healthy in the health
+    # snapshot the batch was prepared with (None off-mesh).
+    shards_healthy: Optional[int] = None
     # (doc, query) cells quarantined by the finite-score guard (poisoned
     # corpus rows surfacing NaN/Inf MaxSim values), summed over shards.
     quarantined: float = 0.0
@@ -498,6 +501,8 @@ class _Prepared(NamedTuple):
     t_prepared: float = 0.0
     stage1_s: float = 0.0
     t_dispatched: float = 0.0
+    # Healthy shards in the snapshot ``coverage`` was computed from.
+    shards_healthy: Optional[int] = None
 
 
 class RetrievalEngine:
@@ -640,13 +645,15 @@ class RetrievalEngine:
         S = len(self._healthy)
         if not 0 <= shard < S:
             raise ValueError(f"shard {shard} out of range [0, {S})")
-        with self._health_lock:
-            went_down = bool(self._healthy[shard]) and not healthy
-            self._healthy[shard] = bool(healthy)
-            snap = self._healthy.copy()
-        if went_down:
-            self.metrics.record_failover()
-        self.metrics.record_shard_health(snap)
+        with TraceAnnotation("engine.shard_health", shard=shard,
+                             healthy=bool(healthy)):
+            with self._health_lock:
+                went_down = bool(self._healthy[shard]) and not healthy
+                self._healthy[shard] = bool(healthy)
+                snap = self._healthy.copy()
+            if went_down:
+                self.metrics.record_failover()
+            self.metrics.record_shard_health(snap)
 
     def fail_shard(self, shard: int) -> None:
         self.set_shard_health(shard, False)
@@ -1258,12 +1265,13 @@ class RetrievalEngine:
                     jnp.asarray(cand_l), jnp.asarray(a_l), jnp.asarray(b_l),
                     self._valid_docs, seed, jnp.asarray(hl)) + knob_args
         else:
-            cov = None
+            cov = hl = None
             args = (self.corpus_embs, self.corpus_mask, jnp.asarray(queries),
                     jnp.asarray(cand), jnp.asarray(a), jnp.asarray(b),
                     seed) + knob_args
         return _Prepared(real, n_real, (tb, nb), flavor, exe, args,
-                         t_release, bid, cov, level, stage1_s=stage1_s)
+                         t_release, bid, cov, level, stage1_s=stage1_s,
+                         shards_healthy=None if hl is None else int(hl.sum()))
 
     @staticmethod
     def _candidate_coverage(cand: np.ndarray, real: Sequence[Request],
@@ -1308,7 +1316,8 @@ class RetrievalEngine:
                 jnp.asarray(queries), self._valid_docs, seed,
                 jnp.asarray(hl), jnp.float32(a_s), jnp.int32(r_c))
         return _Prepared(real, n_real, (tb, nb), flavor, exe, args,
-                         t_release, bid, cov, level)
+                         t_release, bid, cov, level,
+                         shards_healthy=int(hl.sum()))
 
     def _finish_batch(self, prep: _Prepared, out) -> List[Completion]:
         """Completion harvest: the ONLY stage that blocks on the device."""
@@ -1363,6 +1372,7 @@ class RetrievalEngine:
             shard_occupancy=shard_occ,
             shard_rounds=shard_rounds,
             shard_quota_share=shard_quota,
+            shards_healthy=prep.shards_healthy,
             quarantined=quarantined,
             degrade_level=prep.degrade_level,
             bid=prep.bid, t_release=t_release, t_prepared=prep.t_prepared,

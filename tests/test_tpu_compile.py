@@ -3,10 +3,11 @@
 The TPU compiler compiles for a described (not attached) v5e, so these
 tests catch what interpret mode cannot: Mosaic's block-shape rule, i1
 vector reshapes, VMEM and HBM limits. Each test lowers one kernel (or one
-whole bandit rerank step) at the ``colbert-text`` widths (d=128, passages
-of 128 tokens) and at the ``colbert-mm`` page length (729, padded by
-``kernels.ops``), and checks that the Pallas kernel is in the program
-(``tpu_custom_call``) and that the program fits a v5e's 16 GiB of HBM.
+whole bandit rerank step, or the four-chip routed step) at the
+``colbert-text`` widths (d=128, passages of 128 tokens) and at the
+``colbert-mm`` page length (729, padded by ``kernels.ops``), and checks
+that the Pallas kernel is in the program (``tpu_custom_call``) and that
+the program fits a v5e's 16 GiB of HBM.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -18,19 +19,21 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ops
 from repro.kernels.quant import QuantTokens
 from repro.retrieval.ann import STAGE1_CHUNK_DOCS, generate_candidates_batch
-from repro.retrieval.service import make_serving_step
+from repro.retrieval.service import (make_routed_serving_step,
+                                     make_serving_step)
 
 HBM_BYTES = 16 << 30
 M = 128
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     # A compile for a described chip is written to the persistent cache but
@@ -45,8 +48,13 @@ def one_chip():
     except Exception as e:  # no TPU compiler here
         jax.config.update("jax_enable_compilation_cache", was)
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile_for_chip(fn, *args):
@@ -161,3 +169,37 @@ def test_stage1_chunk_topk_lowers_to_tpu_topk(one_chip):
     assert topks.count(f"{B * T},10") == 2, topks
     # A vmapped per-query scan, which sorts, needs 1343019520 bytes of temp.
     assert compiled.memory_analysis().temp_size_in_bytes < 1343019520
+
+
+def test_routed_step_compiles_for_v5e_2x2(topo, monkeypatch):
+    """The four-chip routed program of ``text-routed-4chip`` over a described
+    v5e 2x2 host: 4 x 131072 resident passages, shard-local stage-1 (k'=10,
+    320 candidates a shard), the bandit and the merge in one shard_map. Its
+    only collectives are the merge's (the scorecard all-gathers and the
+    psums), under the ``routed_merge`` scope the benchmark's merge reader
+    and XProf name them by."""
+    monkeypatch.setenv("REPRO_KERNEL_IMPL", "pallas")
+    mesh = Mesh(topo.devices, ("data",))
+    C, L, B, T, Kc, S = 4 * 131072, 128, 8, 32, 8, 4
+
+    def sds(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    step = make_routed_serving_step(mesh, "bandit", topk=5, n_local=320,
+                                    n_total=0, kprime=10, alpha_ef=0.2)
+    compiled = _compile_for_chip(
+        step, sds((C, L, M), jnp.bfloat16, "data"),
+        sds((C, L), jnp.bool_, "data"), sds((Kc, M), jnp.float32),
+        sds((Kc, S), jnp.float32), sds((B, T, M), jnp.float32),
+        sds((S,), jnp.int32), sds((), jnp.int32), sds((S,), jnp.bool_),
+        sds((), jnp.float32), sds((), jnp.int32))
+    text = compiled.as_text()
+    for scope in ("routed_stage1", "routed_rerank", "routed_merge"):
+        assert f"/{scope}/" in text, scope
+    collectives = [ln for ln in text.splitlines() if re.search(
+        r"^\s*%(all-gather|all-reduce|reduce-scatter|collective-permute|"
+        r"all-to-all)", ln)]
+    assert collectives
+    for ln in collectives:
+        assert "/routed_merge/" in ln, ln
