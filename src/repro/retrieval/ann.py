@@ -28,6 +28,8 @@ _NEG = jnp.float32(-3e38)
 # 32 MiB per query at T=32, L=128, so a batch of 8 needs about 256 MiB of
 # temp next to a resident index.
 STAGE1_CHUNK_DOCS = 2048
+# f32 rows in one TPU vector tile (8 rows by 128 lanes).
+_TILE_ROWS = 8
 
 
 class CandidateSet(NamedTuple):
@@ -55,18 +57,42 @@ def token_topk(index_embs, index_mask, query, kprime: int, chunk_docs: int):
     one row of a single (R, M) scan, and each row is selected on its own.
     Callers pass a batch here rather than vmapping: a vmapped ``top_k`` has
     a rank-3 operand, which the TPU compiler lowers to a full sort of each
-    chunk's C*L similarities; a rank-2 one lowers to its TopK.
+    chunk's C*L similarities; a rank-2 one lowers to its TopK. Each chunk
+    is pre-selected by its documents' maxima over their L tokens, so no
+    TopK is as wide as the chunk's tokens.
     Returns (values (..., T, k'), owning doc ids (..., T, k'))."""
     C, L, M = index_embs.shape
     lead = query.shape[:-1]
     q = query.reshape(-1, M).astype(jnp.float32)                   # (R, M)
+    R = q.shape[0]
+    G = -(-R // _TILE_ROWS)
+    q = jnp.pad(q, ((0, G * _TILE_ROWS - R), (0, 0)))
 
     def chunk_topk(embs, mask, start):
         n = embs.shape[0]
         sims = q @ embs.reshape(n * L, M).astype(jnp.float32).T    # (R, n*L)
         sims = jnp.where(mask.reshape(-1)[None, :], sims, _NEG)
-        vals, idx = jax.lax.top_k(sims, min(kprime, n * L))
-        return vals, start + idx // L
+        # A row's top-k' tokens lie in its k' documents of highest max (a
+        # document tied at the k'-th max with a higher id holds only tokens
+        # ranked after the k'-th), so select those documents first, then
+        # their k'*L tokens in position order: the same values and ties as
+        # one top_k over the chunk's n*L columns, from TopKs n and k'*L wide.
+        # The block is viewed as the TPU tiles it, _TILE_ROWS rows by L
+        # tokens a tile, so neither the max nor the gather of whole tiles
+        # copies it; each row then keeps its own row of each gathered tile.
+        kd = min(kprime, n)
+        rows = sims.reshape(G, _TILE_ROWS, n, L)
+        _, docs = jax.lax.top_k(rows.max(axis=3).reshape(-1, n), kd)
+        docs = jnp.sort(docs, axis=1)                              # (R, kd)
+        tiles = jnp.take_along_axis(rows.transpose(0, 2, 1, 3),
+                                    docs.reshape(G, -1)[:, :, None, None],
+                                    axis=1)
+        tiles = tiles.reshape(G, _TILE_ROWS, kd, _TILE_ROWS, L)
+        r = jnp.arange(_TILE_ROWS)
+        toks = tiles[jnp.arange(G)[:, None], r, :, r, :]        # (G, 8, kd, L)
+        vals, idx = jax.lax.top_k(toks.reshape(-1, kd * L),
+                                  min(kprime, kd * L))
+        return vals, start + jnp.take_along_axis(docs, idx // L, axis=1)
 
     def merge(best, new):
         vals, pos = jax.lax.top_k(jnp.concatenate([best[0], new[0]], 1),
@@ -90,7 +116,8 @@ def token_topk(index_embs, index_mask, query, kprime: int, chunk_docs: int):
             best = merge(best, chunk_topk(index_embs[tail:], index_mask[tail:],
                                           tail))
         vals, docs = best
-        return (vals.reshape(*lead, kprime), docs.reshape(*lead, kprime))
+        return (vals[:R].reshape(*lead, kprime),
+                docs[:R].reshape(*lead, kprime))
 
 
 _STAGE1_STATIC = ("kprime", "max_candidates", "support", "chunk_docs")

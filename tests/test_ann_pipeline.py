@@ -92,9 +92,12 @@ def _tied_index(C, L, M, B, T, seed):
 
 
 # (C docs, L tokens, chunk_docs, k'): a ragged last chunk, chunks of one
-# document and wider than the index, and a k' above a chunk's tokens.
+# document and wider than the index, and a k' above a chunk's tokens. The
+# last four pre-select documents in every full chunk (more than k'
+# documents, L > k'); the ragged tails of three documents do not.
 TIED_CASES = [(13, 4, 5, 6), (13, 4, 1, 3), (13, 4, 64, 6), (11, 3, 1, 10),
-              (10, 4, 4, 7)]
+              (10, 4, 4, 7), (13, 8, 5, 3), (21, 6, 6, 3), (16, 5, 8, 2),
+              (9, 16, 9, 4)]
 
 
 @pytest.mark.parametrize("C,L,chunk_docs,kprime", TIED_CASES)
@@ -144,6 +147,58 @@ def test_generate_candidates_batch_matches_per_query(C, L, chunk_docs,
             assert a.shape[0] == B, field
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                           err_msg=field)
+
+
+def _planted_index(C, L, chunk, kprime):
+    """A (C, L, M) index whose similarities with the query row t are
+    embs[:, :, t] (the queries are the unit vectors), so each row's scores
+    are set by hand over small integer noise: row 0 has one document (8)
+    holding all of its top-k' tokens; row 1 has k'-1 documents with one
+    token each above 20 and two documents (13 < 14) whose maxima tie at 20
+    on the k'-th document, where 14's other tokens beat 13's; the third
+    chunk's documents are all masked, and its noise is 99 in every row."""
+    T = M = 8
+    rng = np.random.default_rng(C * 10 + chunk)
+    embs = rng.integers(-8, 9, (C, L, M)).astype(np.float32)
+    embs[8, :kprime, 0] = 40 - np.arange(kprime)
+    embs[[9, 11, 12][:kprime - 1], 0, 1] = [30, 29, 28][:kprime - 1]
+    embs[13, L - 1, 1] = embs[14, 0, 1] = 20
+    embs[14, 1:, 1] = 15
+    embs[2 * chunk:3 * chunk] = 99
+    mask = np.arange(L)[None] < rng.integers(1, L + 1, C)[:, None]
+    mask[[8, 13, 14]] = True
+    mask[2 * chunk:3 * chunk] = False
+    q = np.eye(M, dtype=np.float32).reshape(2, T // 2, M)
+    return (jnp.asarray(embs, jnp.bfloat16), jnp.asarray(mask),
+            jnp.asarray(q))
+
+
+@pytest.mark.parametrize("chunk_docs", [8, 5, 4, 35])
+def test_token_topk_preselection_planted(chunk_docs):
+    """Per-document pre-selection keeps exactly one top_k over the whole
+    (T, C*L) matrix where it could go wrong: one document holding all of a
+    row's top-k' tokens, two documents tied at the k'-th document's max
+    (the lower id wins although the other's next tokens are higher), a
+    chunk of masked documents, and chunks of at most k' documents (4)."""
+    C, L, kprime = 35, 6, 4
+    embs, mask, q = _planted_index(C, L, 8, kprime)
+    vals, docs = token_topk(embs, mask, q, kprime, chunk_docs)
+    sims = jnp.einsum("btm,nm->btn", q,
+                      embs.reshape(C * L, 8).astype(jnp.float32))
+    sims = jnp.where(mask.reshape(-1), sims, jnp.float32(-3e38))
+    w_vals, w_pos = jax.lax.top_k(sims, kprime)
+    np.testing.assert_array_equal(np.asarray(vals), np.asarray(w_vals))
+    np.testing.assert_array_equal(np.asarray(docs), np.asarray(w_pos // L))
+    np.testing.assert_array_equal(np.asarray(docs[0, 0]), [8] * kprime)
+    np.testing.assert_array_equal(np.asarray(vals[0, 1]), [30, 29, 28, 20])
+    np.testing.assert_array_equal(np.asarray(docs[0, 1]), [9, 11, 12, 13])
+    assert not ((docs >= 16) & (docs < 24)).any()
+    kw = dict(kprime=kprime, max_candidates=8, chunk_docs=chunk_docs)
+    got = generate_candidates_batch(embs, mask, q, **kw)
+    want = jax.vmap(lambda qq: generate_candidates(embs, mask, qq, **kw))(q)
+    for field, a, b in zip(got._fields, got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=field)
 
 
 def test_ann_known_cells_match_truth(ds, index):

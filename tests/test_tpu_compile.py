@@ -134,17 +134,45 @@ def test_bandit_rerank_step_compiles_for_v5e(one_chip, monkeypatch):
         assert f"/{scope}/" in text, scope
 
 
+def _selections(text):
+    """(opcode, operand dims) of every sort and TopK in a compiled program;
+    an operand is named in the instruction and shaped where it is made."""
+    dims = {name: tuple(int(d) for d in shape.split(",") if d) for name, shape
+            in re.findall(r"^\s*(?:ROOT )?(%\S+) = \w+\[([\d,]*)\]", text,
+                          re.M)}
+    found = []
+    for ln in text.splitlines():
+        m = re.search(r" (sort|custom-call)\(([^)]*)\)", ln)
+        if m and (m[1] == "sort" or 'custom_call_target="TopK"' in ln):
+            found.append(("sort" if m[1] == "sort" else "TopK",
+                          [dims[o.strip()] for o in m[2].split(",")]))
+    return found
+
+
+def _assert_no_chunk_wide_selection(text, width):
+    """No sort or TopK reads an operand as wide as a chunk's token
+    columns: each chunk is pre-selected by its documents' maxima."""
+    found = _selections(text)
+    assert found
+    for op, operands in found:
+        for shape in operands:
+            assert width not in shape, (op, operands)
+    return found
+
+
 def test_stage1_chunk_topk_lowers_to_tpu_topk(one_chip):
     """The engine's stage-1 program at the text cell's shape: a resident
     bf16 index of 131072 passages, 8 queries of 32 tokens, k'=10 and 320
-    candidates. Each chunk's top-k' over its STAGE1_CHUNK_DOCS * L token
-    columns must be the TPU's TopK, never a sort of those columns (a
-    vmapped, rank-3 top_k lowers to one, and sorting every chunk in full
-    costs many times the chunk's dot)."""
-    C, L, B, T = 131072, 128, 8, 32
+    candidates. Each chunk's top-k' is two TPU TopKs over all B*T
+    query-token rows, one over the chunk's STAGE1_CHUNK_DOCS document
+    maxima and one over the k'*L tokens of the k' documents it keeps,
+    never a sort or TopK of the chunk's STAGE1_CHUNK_DOCS * L token columns
+    (a vmapped, rank-3 top_k lowers to a sort of them, and a TopK over
+    them costs six times the chunk's dot)."""
+    C, L, B, T, K = 131072, 128, 8, 32, 10
 
     def stage1(ce, cm, q):
-        cs = generate_candidates_batch(ce, cm, q, kprime=10,
+        cs = generate_candidates_batch(ce, cm, q, kprime=K,
                                        max_candidates=320)
         return cs.doc_ids, cs.a, cs.b
 
@@ -154,19 +182,13 @@ def test_stage1_chunk_topk_lowers_to_tpu_topk(one_chip):
     compiled = jax.jit(stage1).lower(
         sds((C, L, M), jnp.bfloat16), sds((C, L), jnp.bool_),
         sds((B, T, M), jnp.float32)).compile()
-    text = compiled.as_text()
-    width = STAGE1_CHUNK_DOCS * L
-    lines = text.splitlines()
-    # An instruction's shapes are what precedes its opcode.
-    sorts = [ln.split(" sort(")[0] for ln in lines if " sort(" in ln]
-    assert sorts, "expected the small merge and candidate sorts"
-    for shapes in sorts:
-        assert not re.search(rf"\[[\d,]*\b{width}\]", shapes), shapes
-    topks = [re.search(r"= \(f32\[([\d,]+)\]", ln).group(1) for ln in lines
-             if 'custom_call_target="TopK"' in ln]
-    # Both chunk selections (the first chunk's and the scan body's) are a
-    # TopK of k'=10 over all B*T query-token rows.
-    assert topks.count(f"{B * T},10") == 2, topks
+    found = _assert_no_chunk_wide_selection(compiled.as_text(),
+                                            STAGE1_CHUNK_DOCS * L)
+    assert any(op == "sort" for op, _ in found), "expected the small sorts"
+    topks = [shapes[0] for op, shapes in found if op == "TopK"]
+    # The first chunk's selections and the scan body's.
+    assert topks.count((B * T, STAGE1_CHUNK_DOCS)) == 2, topks
+    assert topks.count((B * T, K * L)) == 2, topks
     # A vmapped per-query scan, which sorts, needs 1343019520 bytes of temp.
     assert compiled.memory_analysis().temp_size_in_bytes < 1343019520
 
@@ -195,6 +217,7 @@ def test_routed_step_compiles_for_v5e_2x2(topo, monkeypatch):
         sds((S,), jnp.int32), sds((), jnp.int32), sds((S,), jnp.bool_),
         sds((), jnp.float32), sds((), jnp.int32))
     text = compiled.as_text()
+    _assert_no_chunk_wide_selection(text, STAGE1_CHUNK_DOCS * L)
     for scope in ("routed_stage1", "routed_rerank", "routed_merge"):
         assert f"/{scope}/" in text, scope
     collectives = [ln for ln in text.splitlines() if re.search(
